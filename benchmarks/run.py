@@ -24,6 +24,9 @@ def main() -> None:
                             figure3_pretrain, roofline, serving_throughput,
                             table1_complexity, table2_downstream,
                             table3_efficiency, train_step)
+    from repro.launch.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
     benches = {
         "table1_complexity": table1_complexity.run,
         "figure1_spectrum": figure1_spectrum.run,

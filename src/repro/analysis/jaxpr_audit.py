@@ -216,20 +216,19 @@ def audit_sp_causal(expect_lin: Optional[int] = None,
 
     from repro.core.seq_parallel import (blockwise_sp_comm_bytes,
                                          sp_blockwise_causal_attention)
-    from repro.parallel.sharding import shard_map
 
     d = _SP
     q, k, v, ke, kf = _sp_inputs()
     E = jax.random.normal(ke, (d["c"], d["r"]), jnp.float32) * 0.3
     F = jax.random.normal(kf, (d["c"], d["r"]), jnp.float32) * 0.3
-    mesh = AbstractMesh((("seq", d["shards"]),))
+    mesh = AbstractMesh((d["shards"],), ("seq",))
 
     def body(q_l, k_l, v_l):
         return sp_blockwise_causal_attention(
             q_l, k_l, v_l, E, F, seq_axis="seq", block_size=d["c"],
             block_slots=d["r"], scale=d["Dh"] ** -0.5, fused=False)
 
-    sharded = shard_map(
+    sharded = jax.shard_map(
         body, mesh=mesh,
         in_specs=(P(None, "seq"), P(None, "seq"), P(None, "seq")),
         out_specs=P(None, "seq"), check_vma=False)
@@ -268,21 +267,20 @@ def audit_sp_exact(expect_lin: Optional[int] = None,
 
     from repro.core.seq_parallel import (seq_parallel_comm_bytes,
                                          sp_exact_linformer_attention)
-    from repro.parallel.sharding import shard_map
 
     d = _SP
     K = (d["S"] // d["c"]) * d["r"]          # compressed width
     q, k, v, ke, kf = _sp_inputs()
     E = jax.random.normal(ke, (d["S"], K), jnp.float32) * 0.3
     F = jax.random.normal(kf, (d["S"], K), jnp.float32) * 0.3
-    mesh = AbstractMesh((("seq", d["shards"]),))
+    mesh = AbstractMesh((d["shards"],), ("seq",))
 
     def body(q_l, k_l, v_l, E_l, F_l):
         return sp_exact_linformer_attention(
             q_l, k_l, v_l, E_l, F_l, seq_axis="seq",
             scale=d["Dh"] ** -0.5, fused=False)
 
-    sharded = shard_map(
+    sharded = jax.shard_map(
         body, mesh=mesh,
         in_specs=(P(None, "seq"), P(None, "seq"), P(None, "seq"),
                   P("seq"), P("seq")),

@@ -38,7 +38,7 @@ from jax.sharding import PartitionSpec as P
 
 from repro.core import causal as causal_lib
 from repro.core import linformer as lin_lib
-from repro.parallel.sharding import ParallelCtx, shard_map as _shard_map
+from repro.parallel.sharding import ParallelCtx
 
 
 # ---------------------------------------------------------------------------
@@ -157,7 +157,7 @@ def seq_parallel_linformer_attention(
             q_l, k_l, v_l, E_l, F_l, seq_axis=axis, scale=scale_,
             fused=False)
 
-    return _shard_map(
+    return jax.shard_map(
         body, mesh=mesh,
         in_specs=(P(None, axis, None, None), P(None, axis, None, None),
                   P(None, axis, None, None), P(axis, None), P(axis, None)),

@@ -41,7 +41,21 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.kernels.common import BWD_VMEM_LIMIT_BYTES, dequant, rows
+
 NEG_INF = -1e30
+
+
+def start_block_rows(start_blocks, B: int):
+    """(B,) per-row start blocks as a (B, 1, 1) int32 array: one (1, 1, 1)
+    block per row, whose last two dims equal the array's (Mosaic's rule)."""
+    return jnp.asarray(start_blocks, jnp.int32).reshape(B, 1, 1)
+
+
+def start_block_spec(row):
+    """SMEM block of row `row(*grid_idx)`'s start block (`start_block_rows`)."""
+    return pl.BlockSpec((1, 1, 1), lambda *idx: (row(*idx), 0, 0),
+                        memory_space=pltpu.SMEM)
 
 
 def _joint_scores(q, kl, kbar, blk_cut, scale, r):
@@ -66,8 +80,8 @@ def _joint_scores(q, kl, kbar, blk_cut, scale, r):
 
 def _attend_block(q, kl, vl, kbar, vbar, n, scale, r):
     """One query block's joint-softmax attention: returns (out fp32, m,
-    denom) — the single forward body shared by the plain and
-    residual-emitting kernels, so grad-time primal and inference forward can
+    denom) — the single forward body shared by the plain, residual-emitting
+    and quantized kernels, so grad-time primal and inference forward can
     never diverge."""
     s_loc, s_glob = _joint_scores(q, kl, kbar, n, scale, r)
     m = jnp.maximum(jnp.max(s_loc, -1, keepdims=True),
@@ -85,38 +99,18 @@ def _attend_block(q, kl, vl, kbar, vbar, n, scale, r):
     return out, m, denom
 
 
-def _kernel(q_ref, kl_ref, vl_ref, kbar_ref, vbar_ref, out_ref, *,
-            scale: float, r: int):
-    n = pl.program_id(1)
-    out, _, _ = _attend_block(q_ref[0], kl_ref[0], vl_ref[0], kbar_ref[0],
-                              vbar_ref[0], n, scale, r)
-    out_ref[0] = out.astype(out_ref.dtype)
-
-
-def _kernel_res(q_ref, kl_ref, vl_ref, kbar_ref, vbar_ref,
-                out_ref, m_ref, denom_ref, *, scale: float, r: int):
-    """Forward variant that also emits the softmax residuals (per-row max and
-    denominator, fp32) the fused backward recomputes the probabilities from."""
-    n = pl.program_id(1)
-    out, m, denom = _attend_block(q_ref[0], kl_ref[0], vl_ref[0],
-                                  kbar_ref[0], vbar_ref[0], n, scale, r)
-    out_ref[0] = out.astype(out_ref.dtype)
-    m_ref[0] = m[:, 0]
-    denom_ref[0] = denom[:, 0]
-
-
 def _prefix_kernel(q_ref, kl_ref, vl_ref, ck_ref, cv_ref, nb0_ref, out_ref, *,
                    scale: float, r: int):
-    """Chunk-prefill/sequence-parallel variant of `_kernel`: the compressed
-    operand is a FULL slot buffer (the slot-resident cache, or the gathered
-    sequence-parallel prefix — pinned either way) and the visibility cut
-    shifts by the row's start block nb0 — grid block n of the chunk is
-    absolute block nb0 + n, so it sees slots of blocks < nb0 + n. nb0
-    arrives as a per-row (1, 1) int32 block (SMEM-friendly scalar layout;
-    interpret mode reads it directly). Shares `_attend_block` with the
-    offset-zero training kernel so the two forms can never diverge."""
+    """Forward of one query block: the compressed operand is a FULL slot
+    buffer (the sequence's own slots in training, the slot-resident cache
+    in chunk prefill, or the gathered sequence-parallel prefix — pinned
+    either way) and the visibility cut shifts by the row's start block nb0
+    — grid block n of the chunk is absolute block nb0 + n, so it sees slots
+    of blocks < nb0 + n. nb0 arrives as the row's (1, 1, 1) int32 block in
+    SMEM. Training runs it at nb0 = 0, so the offset-free and offset forms
+    are one kernel."""
     n = pl.program_id(1)
-    nb0 = nb0_ref[0, 0]
+    nb0 = nb0_ref[0, 0, 0]
     out, _, _ = _attend_block(q_ref[0], kl_ref[0], vl_ref[0], ck_ref[0],
                               cv_ref[0], n + nb0, scale, r)
     out_ref[0] = out.astype(out_ref.dtype)
@@ -125,15 +119,16 @@ def _prefix_kernel(q_ref, kl_ref, vl_ref, ck_ref, cv_ref, nb0_ref, out_ref, *,
 def _prefix_kernel_res(q_ref, kl_ref, vl_ref, ck_ref, cv_ref, nb0_ref,
                        out_ref, m_ref, denom_ref, *, scale: float, r: int):
     """`_prefix_kernel` that also emits the softmax residuals (per-row max
-    and denominator, fp32) — what makes the prefix form trainable: the fused
-    backward recomputes the joint probabilities from them."""
+    and denominator, fp32, stored lane-dense as (1, c) rows) — what makes
+    the forward trainable: the fused backward recomputes the joint
+    probabilities from them."""
     n = pl.program_id(1)
-    nb0 = nb0_ref[0, 0]
+    nb0 = nb0_ref[0, 0, 0]
     out, m, denom = _attend_block(q_ref[0], kl_ref[0], vl_ref[0], ck_ref[0],
                                   cv_ref[0], n + nb0, scale, r)
     out_ref[0] = out.astype(out_ref.dtype)
-    m_ref[0] = m[:, 0]
-    denom_ref[0] = denom[:, 0]
+    m_ref[0] = m.reshape(1, -1)
+    denom_ref[0] = denom.reshape(1, -1)
 
 
 def _prefix_kernel_q(q_ref, kl_ref, vl_ref, ck_ref, cv_ref, cks_ref, cvs_ref,
@@ -145,9 +140,9 @@ def _prefix_kernel_q(q_ref, kl_ref, vl_ref, ck_ref, cv_ref, cks_ref, cvs_ref,
     dequantized prefix is fp32, and lax.dot_general needs matching operand
     dtypes)."""
     n = pl.program_id(1)
-    nb0 = nb0_ref[0, 0]
-    ck = ck_ref[0].astype(jnp.float32) * cks_ref[...][0][:, None]
-    cv = cv_ref[0].astype(jnp.float32) * cvs_ref[...][0][:, None]
+    nb0 = nb0_ref[0, 0, 0]
+    ck = dequant(ck_ref[0], cks_ref[0])
+    cv = dequant(cv_ref[0], cvs_ref[0])
     out, _, _ = _attend_block(
         q_ref[0].astype(jnp.float32), kl_ref[0].astype(jnp.float32),
         vl_ref[0].astype(jnp.float32), ck, cv, n + nb0, scale, r)
@@ -186,9 +181,7 @@ def blockwise_causal_prefix_attn_q(
     v3 = v.reshape(B * Hkv, P, Dh)
     ck3 = comp_k.reshape(B * Hkv, M, Dh)
     cv3 = comp_v.reshape(B * Hkv, M, Dh)
-    cks = comp_k_s.astype(jnp.float32).reshape(B * Hkv, M)
-    cvs = comp_v_s.astype(jnp.float32).reshape(B * Hkv, M)
-    nb0 = jnp.asarray(start_blocks, jnp.int32).reshape(B, 1)
+    nb0 = start_block_rows(start_blocks, B)
 
     def kv_row(bh):
         return (bh // H) * Hkv + (bh % H) // G
@@ -202,14 +195,15 @@ def blockwise_causal_prefix_attn_q(
             pl.BlockSpec((1, c, Dh), lambda bh, n: (kv_row(bh), n, 0)),
             pl.BlockSpec((1, M, Dh), lambda bh, n: (kv_row(bh), 0, 0)),
             pl.BlockSpec((1, M, Dh), lambda bh, n: (kv_row(bh), 0, 0)),
-            pl.BlockSpec((1, M), lambda bh, n: (kv_row(bh), 0)),
-            pl.BlockSpec((1, M), lambda bh, n: (kv_row(bh), 0)),
-            pl.BlockSpec((1, 1), lambda bh, n: (bh // H, 0)),
+            pl.BlockSpec((1, 1, M), lambda bh, n: (kv_row(bh), 0, 0)),
+            pl.BlockSpec((1, 1, M), lambda bh, n: (kv_row(bh), 0, 0)),
+            start_block_spec(lambda bh, n: bh // H),
         ],
         out_specs=pl.BlockSpec((1, c, Dh), lambda bh, n: (bh, n, 0)),
         out_shape=jax.ShapeDtypeStruct((B * H, P, Dh), q.dtype),
         interpret=interpret,
-    )(q3, k3, v3, ck3, cv3, cks, cvs, nb0)
+    )(q3, k3, v3, ck3, cv3, rows(comp_k_s, B * Hkv),
+      rows(comp_v_s, B * Hkv), nb0)
     return out.reshape(B, H, P, Dh)
 
 
@@ -234,8 +228,8 @@ def blockwise_causal_prefix_attn(
 
     Same grid/GQA routing as :func:`blockwise_causal_attn`, but the pinned
     compressed operand is the FULL (M_total, Dh) slot buffer and the
-    causality cut is shifted per row by `start_blocks` (passed as a (B, 1)
-    int32 scalar block). M_total = (max_seq/c)·r must fit in VMEM — the same
+    causality cut is shifted per row by `start_blocks` (one int32 per row,
+    read from SMEM). M_total = (max_seq/c)·r must fit in VMEM — the same
     compression budget the decode kernel already pins. With
     ``return_residuals=True`` also emits the joint softmax's per-row
     (m, denom), each (B, H, P) fp32 — the residuals
@@ -255,7 +249,7 @@ def blockwise_causal_prefix_attn(
     v3 = v.reshape(B * Hkv, P, Dh)
     ck3 = comp_k.reshape(B * Hkv, M, Dh)
     cv3 = comp_v.reshape(B * Hkv, M, Dh)
-    nb0 = jnp.asarray(start_blocks, jnp.int32).reshape(B, 1)
+    nb0 = start_block_rows(start_blocks, B)
 
     def kv_row(bh):
         return (bh // H) * Hkv + (bh % H) // G
@@ -266,23 +260,19 @@ def blockwise_causal_prefix_attn(
         pl.BlockSpec((1, c, Dh), lambda bh, n: (kv_row(bh), n, 0)),
         pl.BlockSpec((1, M, Dh), lambda bh, n: (kv_row(bh), 0, 0)),
         pl.BlockSpec((1, M, Dh), lambda bh, n: (kv_row(bh), 0, 0)),
-        pl.BlockSpec((1, 1), lambda bh, n: (bh // H, 0)),
+        start_block_spec(lambda bh, n: bh // H),
     ]
+    out_spec = pl.BlockSpec((1, c, Dh), lambda bh, n: (bh, n, 0))
+    out_shape = jax.ShapeDtypeStruct((B * H, P, Dh), q.dtype)
     if return_residuals:
+        res_spec = pl.BlockSpec((1, 1, c), lambda bh, n: (bh, 0, n))
+        res_shape = jax.ShapeDtypeStruct((B * H, 1, P), jnp.float32)
         out, m, denom = pl.pallas_call(
             functools.partial(_prefix_kernel_res, scale=scale, r=block_slots),
             grid=(B * H, nb),
             in_specs=in_specs,
-            out_specs=[
-                pl.BlockSpec((1, c, Dh), lambda bh, n: (bh, n, 0)),
-                pl.BlockSpec((1, c), lambda bh, n: (bh, n)),
-                pl.BlockSpec((1, c), lambda bh, n: (bh, n)),
-            ],
-            out_shape=[
-                jax.ShapeDtypeStruct((B * H, P, Dh), q.dtype),
-                jax.ShapeDtypeStruct((B * H, P), jnp.float32),
-                jax.ShapeDtypeStruct((B * H, P), jnp.float32),
-            ],
+            out_specs=[out_spec, res_spec, res_spec],
+            out_shape=[out_shape, res_shape, res_shape],
             interpret=interpret,
         )(q3, k3, v3, ck3, cv3, nb0)
         return (out.reshape(B, H, P, Dh), m.reshape(B, H, P),
@@ -291,8 +281,8 @@ def blockwise_causal_prefix_attn(
         functools.partial(_prefix_kernel, scale=scale, r=block_slots),
         grid=(B * H, nb),
         in_specs=in_specs,
-        out_specs=pl.BlockSpec((1, c, Dh), lambda bh, n: (bh, n, 0)),
-        out_shape=jax.ShapeDtypeStruct((B * H, P, Dh), q.dtype),
+        out_specs=out_spec,
+        out_shape=out_shape,
         interpret=interpret,
     )(q3, k3, v3, ck3, cv3, nb0)
     return out.reshape(B, H, P, Dh)
@@ -311,67 +301,20 @@ def blockwise_causal_attn(
     interpret: bool = False,
     return_residuals: bool = False,
 ):
-    """Fused blockwise-causal attention forward.
+    """Fused blockwise-causal attention forward: the prefix form at start
+    block 0 with the sequence's own M = (S/c)·r slots as the buffer.
 
     With ``return_residuals=True`` also returns the joint softmax's per-row
     max `m` and denominator (each (B, H, S) fp32) — the residuals
     :func:`blockwise_causal_attn_bwd` recomputes the probabilities from.
     """
-    B, H, S, Dh = q.shape
-    Hkv = k.shape[1]
-    assert H % Hkv == 0, (H, Hkv)
-    G = H // Hkv
-    c = block_size
-    assert S % c == 0
-    nb = S // c
-    M = kbar.shape[2]
-    assert M == nb * block_slots, (M, nb, block_slots)
-    q3 = q.reshape(B * H, S, Dh)
-    k3 = k.reshape(B * Hkv, S, Dh)
-    v3 = v.reshape(B * Hkv, S, Dh)
-    kb3 = kbar.reshape(B * Hkv, M, Dh)
-    vb3 = vbar.reshape(B * Hkv, M, Dh)
-
-    # grid row b·H + h reads kv row b·Hkv + h//G — the GQA group share
-    # happens in the index map, never as a repeated HBM tensor.
-    def kv_row(bh):
-        return (bh // H) * Hkv + (bh % H) // G
-
-    in_specs = [
-        pl.BlockSpec((1, c, Dh), lambda bh, n: (bh, n, 0)),
-        pl.BlockSpec((1, c, Dh), lambda bh, n: (kv_row(bh), n, 0)),
-        pl.BlockSpec((1, c, Dh), lambda bh, n: (kv_row(bh), n, 0)),
-        pl.BlockSpec((1, M, Dh), lambda bh, n: (kv_row(bh), 0, 0)),
-        pl.BlockSpec((1, M, Dh), lambda bh, n: (kv_row(bh), 0, 0)),
-    ]
-    if return_residuals:
-        out, m, denom = pl.pallas_call(
-            functools.partial(_kernel_res, scale=scale, r=block_slots),
-            grid=(B * H, nb),
-            in_specs=in_specs,
-            out_specs=[
-                pl.BlockSpec((1, c, Dh), lambda bh, n: (bh, n, 0)),
-                pl.BlockSpec((1, c), lambda bh, n: (bh, n)),
-                pl.BlockSpec((1, c), lambda bh, n: (bh, n)),
-            ],
-            out_shape=[
-                jax.ShapeDtypeStruct((B * H, S, Dh), q.dtype),
-                jax.ShapeDtypeStruct((B * H, S), jnp.float32),
-                jax.ShapeDtypeStruct((B * H, S), jnp.float32),
-            ],
-            interpret=interpret,
-        )(q3, k3, v3, kb3, vb3)
-        return (out.reshape(B, H, S, Dh), m.reshape(B, H, S),
-                denom.reshape(B, H, S))
-    out = pl.pallas_call(
-        functools.partial(_kernel, scale=scale, r=block_slots),
-        grid=(B * H, nb),
-        in_specs=in_specs,
-        out_specs=pl.BlockSpec((1, c, Dh), lambda bh, n: (bh, n, 0)),
-        out_shape=jax.ShapeDtypeStruct((B * H, S, Dh), q.dtype),
-        interpret=interpret,
-    )(q3, k3, v3, kb3, vb3)
-    return out.reshape(B, H, S, Dh)
+    B, S = q.shape[0], q.shape[2]
+    assert kbar.shape[2] == (S // block_size) * block_slots, (
+        kbar.shape, S, block_size, block_slots)
+    return blockwise_causal_prefix_attn(
+        q, k, v, kbar, vbar, jnp.zeros((B,), jnp.int32),
+        block_size=block_size, block_slots=block_slots, scale=scale,
+        interpret=interpret, return_residuals=return_residuals)
 
 
 def _bwd_kernel(q_ref, kl_ref, vl_ref, kbar_ref, vbar_ref, m_ref, d_ref,
@@ -390,7 +333,7 @@ def _bwd_kernel(q_ref, kl_ref, vl_ref, kbar_ref, vbar_ref, m_ref, d_ref,
     contribute nothing, so the full-buffer accumulators stay exact."""
     n = pl.program_id(1)
     g = pl.program_id(2)
-    nb0 = nb0_ref[0, 0]
+    nb0 = nb0_ref[0, 0, 0]
 
     @pl.when(jnp.logical_and(n == 0, g == 0))
     def _init_glob():
@@ -408,8 +351,8 @@ def _bwd_kernel(q_ref, kl_ref, vl_ref, kbar_ref, vbar_ref, m_ref, d_ref,
     vl32 = vl_ref[0].astype(jnp.float32)
     vbar32 = vbar_ref[0].astype(jnp.float32)
     do = do_ref[0].astype(jnp.float32)               # (c, Dh)
-    m = m_ref[...].reshape(-1, 1)                    # (c, 1) fp32
-    denom = d_ref[...].reshape(-1, 1)
+    m = m_ref[0].reshape(-1, 1)                      # (1, c) row → (c, 1)
+    denom = d_ref[0].reshape(-1, 1)
 
     # native-dtype score recompute — bit-identical to the forward's scores,
     # so p = exp(s − m)/denom reproduces the forward's exact probabilities
@@ -511,14 +454,14 @@ def blockwise_causal_attn_bwd(
     if start_blocks is None:
         assert M == nb * block_slots, (M, nb, block_slots)
         start_blocks = jnp.zeros((B,), jnp.int32)
-    nb0 = jnp.asarray(start_blocks, jnp.int32).reshape(B, 1)
+    nb0 = start_block_rows(start_blocks, B)
     q3 = q.reshape(B * H, S, Dh)
     k3 = k.reshape(B * Hkv, S, Dh)
     v3 = v.reshape(B * Hkv, S, Dh)
     kb3 = kbar.reshape(B * Hkv, M, Dh)
     vb3 = vbar.reshape(B * Hkv, M, Dh)
-    m3 = m.reshape(B * H, S)
-    d3 = denom.reshape(B * H, S)
+    m3 = m.reshape(B * H, 1, S)
+    d3 = denom.reshape(B * H, 1, S)
     do3 = do.reshape(B * H, S, Dh)
 
     # kv row bkv, group member g ↔ query row (bkv//Hkv)·H + (bkv%Hkv)·G + g —
@@ -527,28 +470,17 @@ def blockwise_causal_attn_bwd(
     def q_row(bkv, g):
         return (bkv // Hkv) * H + (bkv % Hkv) * G + g
 
+    q_blk = pl.BlockSpec((1, c, Dh), lambda bkv, n, g: (q_row(bkv, g), n, 0))
+    kv_blk = pl.BlockSpec((1, c, Dh), lambda bkv, n, g: (bkv, n, 0))
+    slot_blk = pl.BlockSpec((1, M, Dh), lambda bkv, n, g: (bkv, 0, 0))
+    res_blk = pl.BlockSpec((1, 1, c), lambda bkv, n, g: (q_row(bkv, g), 0, n))
     dq, dkl, dvl, dkb, dvb = pl.pallas_call(
         functools.partial(_bwd_kernel, scale=scale, r=block_slots, nb=nb,
                           G=G),
         grid=(B * Hkv, nb, G),
-        in_specs=[
-            pl.BlockSpec((1, c, Dh), lambda bkv, n, g: (q_row(bkv, g), n, 0)),
-            pl.BlockSpec((1, c, Dh), lambda bkv, n, g: (bkv, n, 0)),
-            pl.BlockSpec((1, c, Dh), lambda bkv, n, g: (bkv, n, 0)),
-            pl.BlockSpec((1, M, Dh), lambda bkv, n, g: (bkv, 0, 0)),
-            pl.BlockSpec((1, M, Dh), lambda bkv, n, g: (bkv, 0, 0)),
-            pl.BlockSpec((1, c), lambda bkv, n, g: (q_row(bkv, g), n)),
-            pl.BlockSpec((1, c), lambda bkv, n, g: (q_row(bkv, g), n)),
-            pl.BlockSpec((1, c, Dh), lambda bkv, n, g: (q_row(bkv, g), n, 0)),
-            pl.BlockSpec((1, 1), lambda bkv, n, g: (bkv // Hkv, 0)),
-        ],
-        out_specs=[
-            pl.BlockSpec((1, c, Dh), lambda bkv, n, g: (q_row(bkv, g), n, 0)),
-            pl.BlockSpec((1, c, Dh), lambda bkv, n, g: (bkv, n, 0)),
-            pl.BlockSpec((1, c, Dh), lambda bkv, n, g: (bkv, n, 0)),
-            pl.BlockSpec((1, M, Dh), lambda bkv, n, g: (bkv, 0, 0)),
-            pl.BlockSpec((1, M, Dh), lambda bkv, n, g: (bkv, 0, 0)),
-        ],
+        in_specs=[q_blk, kv_blk, kv_blk, slot_blk, slot_blk, res_blk, res_blk,
+                  q_blk, start_block_spec(lambda bkv, n, g: bkv // Hkv)],
+        out_specs=[q_blk, kv_blk, kv_blk, slot_blk, slot_blk],
         out_shape=[
             jax.ShapeDtypeStruct((B * H, S, Dh), q.dtype),
             jax.ShapeDtypeStruct((B * Hkv, S, Dh), jnp.float32),
@@ -562,6 +494,8 @@ def blockwise_causal_attn_bwd(
             pltpu.VMEM((M, Dh), jnp.float32),
             pltpu.VMEM((M, Dh), jnp.float32),
         ],
+        compiler_params=pltpu.CompilerParams(
+            vmem_limit_bytes=BWD_VMEM_LIMIT_BYTES),
         interpret=interpret,
     )(q3, k3, v3, kb3, vb3, m3, d3, do3, nb0)
     return (dq.reshape(B, H, S, Dh), dkl.reshape(B, Hkv, S, Dh),

@@ -1,5 +1,5 @@
 """Shared kernel-wrapper plumbing: layout moves, backend resolution, grid
-sizing and the VMEM fail-fast budgets.
+sizing, the VMEM fail-fast budgets and the per-row operand layout.
 
 One home for the helpers both `kernels/ops.py` (the jit'd shard-local kernel
 wrappers) and `parallel/plan.py` (the mesh-aware execution plan) consume —
@@ -21,6 +21,12 @@ BACKWARD_IMPLS = ("fused", "reference")
 # blow VMEM (or silently thrash) at runtime — now the wrappers fail fast.
 MAX_EXACT_K = 512          # exact form: compressed length of k̄/v̄
 MAX_PINNED_SLOTS = 4096    # causal/decode/chunk forms: M = (max_seq/c)·r
+
+# Scoped-VMEM limit of the fused causal backward. It pins (M, Dh) fp32
+# accumulators and outputs and holds (c, M) fp32 score-shaped temporaries:
+# at M = MAX_PINNED_SLOTS, c = 256, Dh = 128 Mosaic asks for ~22 MiB, above
+# the 16 MiB default scoped limit (a v5e core has 128 MiB of VMEM).
+BWD_VMEM_LIMIT_BYTES = 32 * 2**20
 
 # Grids tile the sequence into blocks that must divide it evenly; blocks
 # below this floor degrade the grid to near-per-row steps (S=509 prime would
@@ -48,9 +54,9 @@ def resolve_backend(backend: str = "auto") -> str:
     """Resolve an `AttentionConfig.backend` knob to a concrete backend.
 
     "auto" per platform: TPU -> fused (Mosaic-compiled); CPU -> fused in
-    interpret mode (the kernel logic is the validated default path on this
-    container); any other platform (e.g. GPU, which has no Mosaic lowering
-    and where interpret mode would be pathologically slow) -> reference.
+    interpret mode (how the tests run the kernel logic). Any other platform
+    has no Mosaic lowering, so "auto" refuses it rather than quietly
+    running something else; pass "reference" to run there on purpose.
     """
     if backend in BACKENDS:
         return backend
@@ -58,7 +64,13 @@ def resolve_backend(backend: str = "auto") -> str:
         raise ValueError(
             f"unknown attention backend {backend!r}; "
             f"expected 'auto' or one of {BACKENDS}")
-    return "fused" if jax.default_backend() in ("tpu", "cpu") else "reference"
+    platform = jax.default_backend()
+    if platform not in ("tpu", "cpu"):
+        raise ValueError(
+            f"attention backend 'auto' serves TPU (Mosaic) and CPU "
+            f"(interpret mode), not {platform!r}; set backend='reference' "
+            f"to run the pure-jnp path there")
+    return "fused"
 
 
 def resolve_backward_impl(backward_impl: str) -> str:
@@ -104,3 +116,17 @@ def repeat_kv(x, H):             # (B,Hkv,K,D) -> (B,H,K,D)
     if Hkv == H:
         return x
     return jnp.repeat(x, H // Hkv, axis=1)
+
+
+def rows(x, n: int):
+    """Per-row fp32 vectors as (n, 1, L) for a kernel operand: a (1, 1, L)
+    block then has its last two dims equal to the array's, which Mosaic
+    requires of any L (a (1, L) block of an (n, L) array is refused)."""
+    return x.astype(jnp.float32).reshape(n, 1, -1)
+
+
+def dequant(x, s_row):
+    """In-kernel dequantization: (N, Dh) int8/fp8 values times their (1, N)
+    fp32 scale row (a `rows` block) → fp32. The reshape turns the lane-dense
+    row into a column in VMEM."""
+    return x.astype(jnp.float32) * s_row.reshape(-1, 1)

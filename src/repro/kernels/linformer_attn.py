@@ -37,6 +37,8 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from repro.kernels.common import dequant, rows
+
 
 def _softmax_attend(q, kbar, vbar, scale):
     s = jax.lax.dot_general(
@@ -121,7 +123,7 @@ def _attend_pinned(q, rk, rv, ck, cv, bl, bg, scale):
 def _decode_kernel(q_ref, rk_ref, rv_ref, ck_ref, cv_ref, bl_ref, bg_ref,
                    out_ref, *, scale: float):
     out = _attend_pinned(q_ref[0], rk_ref[0], rv_ref[0], ck_ref[0],
-                         cv_ref[0], bl_ref[...], bg_ref[...], scale)
+                         cv_ref[0], bl_ref[0], bg_ref[0], scale)
     out_ref[0] = out.astype(out_ref.dtype)
 
 
@@ -131,12 +133,12 @@ def _decode_kernel_q(q_ref, rk_ref, rv_ref, ck_ref, cv_ref,
     """Quantized-cache decode kernel: operands arrive int8/fp8 with per-token
     (ring) / per-slot (pages) fp32 scales and are dequantized IN VMEM —
     HBM traffic for the two pinned caches shrinks with the storage dtype."""
-    rk = rk_ref[0].astype(jnp.float32) * rks_ref[...][0][:, None]
-    rv = rv_ref[0].astype(jnp.float32) * rvs_ref[...][0][:, None]
-    ck = ck_ref[0].astype(jnp.float32) * cks_ref[...][0][:, None]
-    cv = cv_ref[0].astype(jnp.float32) * cvs_ref[...][0][:, None]
+    rk = dequant(rk_ref[0], rks_ref[0])
+    rv = dequant(rv_ref[0], rvs_ref[0])
+    ck = dequant(ck_ref[0], cks_ref[0])
+    cv = dequant(cv_ref[0], cvs_ref[0])
     out = _attend_pinned(q_ref[0].astype(jnp.float32), rk, rv, ck, cv,
-                         bl_ref[...], bg_ref[...], scale)
+                         bl_ref[0], bg_ref[0], scale)
     out_ref[0] = out.astype(out_ref.dtype)
 
 
@@ -164,16 +166,15 @@ def decode_attn(
             pl.BlockSpec((1, c, Dh), lambda bh: (bh, 0, 0)),
             pl.BlockSpec((1, M, Dh), lambda bh: (bh, 0, 0)),
             pl.BlockSpec((1, M, Dh), lambda bh: (bh, 0, 0)),
-            pl.BlockSpec((1, c), lambda bh: (bh // Hkv, 0)),
-            pl.BlockSpec((1, M), lambda bh: (bh // Hkv, 0)),
+            pl.BlockSpec((1, 1, c), lambda bh: (bh // Hkv, 0, 0)),
+            pl.BlockSpec((1, 1, M), lambda bh: (bh // Hkv, 0, 0)),
         ],
         out_specs=pl.BlockSpec((1, G, Dh), lambda bh: (bh, 0, 0)),
         out_shape=jax.ShapeDtypeStruct((B * Hkv, G, Dh), q.dtype),
         interpret=interpret,
     )(q.reshape(B * Hkv, G, Dh), raw_k.reshape(B * Hkv, c, Dh),
       raw_v.reshape(B * Hkv, c, Dh), comp_k.reshape(B * Hkv, M, Dh),
-      comp_v.reshape(B * Hkv, M, Dh), bias_loc.astype(jnp.float32),
-      bias_glob.astype(jnp.float32))
+      comp_v.reshape(B * Hkv, M, Dh), rows(bias_loc, B), rows(bias_glob, B))
     return out.reshape(B, Hkv, G, Dh)
 
 
@@ -202,7 +203,6 @@ def decode_attn_q(
     c, M = raw_k.shape[2], comp_k.shape[2]
     grid = (B * Hkv,)
     kv3 = lambda x, n: x.reshape(B * Hkv, n, Dh)
-    sc2 = lambda x, n: x.astype(jnp.float32).reshape(B * Hkv, n)
     out = pl.pallas_call(
         functools.partial(_decode_kernel_q, scale=scale),
         grid=grid,
@@ -212,18 +212,18 @@ def decode_attn_q(
             pl.BlockSpec((1, c, Dh), lambda bh: (bh, 0, 0)),
             pl.BlockSpec((1, M, Dh), lambda bh: (bh, 0, 0)),
             pl.BlockSpec((1, M, Dh), lambda bh: (bh, 0, 0)),
-            pl.BlockSpec((1, c), lambda bh: (bh, 0)),
-            pl.BlockSpec((1, c), lambda bh: (bh, 0)),
-            pl.BlockSpec((1, M), lambda bh: (bh, 0)),
-            pl.BlockSpec((1, M), lambda bh: (bh, 0)),
-            pl.BlockSpec((1, c), lambda bh: (bh // Hkv, 0)),
-            pl.BlockSpec((1, M), lambda bh: (bh // Hkv, 0)),
+            pl.BlockSpec((1, 1, c), lambda bh: (bh, 0, 0)),
+            pl.BlockSpec((1, 1, c), lambda bh: (bh, 0, 0)),
+            pl.BlockSpec((1, 1, M), lambda bh: (bh, 0, 0)),
+            pl.BlockSpec((1, 1, M), lambda bh: (bh, 0, 0)),
+            pl.BlockSpec((1, 1, c), lambda bh: (bh // Hkv, 0, 0)),
+            pl.BlockSpec((1, 1, M), lambda bh: (bh // Hkv, 0, 0)),
         ],
         out_specs=pl.BlockSpec((1, G, Dh), lambda bh: (bh, 0, 0)),
         out_shape=jax.ShapeDtypeStruct((B * Hkv, G, Dh), q.dtype),
         interpret=interpret,
     )(q.reshape(B * Hkv, G, Dh), kv3(raw_k, c), kv3(raw_v, c),
-      kv3(comp_k, M), kv3(comp_v, M), sc2(raw_k_s, c), sc2(raw_v_s, c),
-      sc2(comp_k_s, M), sc2(comp_v_s, M), bias_loc.astype(jnp.float32),
-      bias_glob.astype(jnp.float32))
+      kv3(comp_k, M), kv3(comp_v, M), rows(raw_k_s, B * Hkv),
+      rows(raw_v_s, B * Hkv), rows(comp_k_s, B * Hkv),
+      rows(comp_v_s, B * Hkv), rows(bias_loc, B), rows(bias_glob, B))
     return out.reshape(B, Hkv, G, Dh)

@@ -1,9 +1,8 @@
-import os
-os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
-
 """Multi-pod dry-run: lower + compile every (architecture × input-shape) cell
 on the production mesh, WITHOUT allocating real tensors, and extract the
-roofline terms from the compiled artifact.
+roofline terms from the compiled artifact. It runs on the CPU with 512 host
+devices standing in for the chips, set only when run as a script (the
+device count locks at the first jax import); importing it changes nothing.
 
   python -m repro.launch.dryrun --arch qwen3-8b --shape train_4k
   python -m repro.launch.dryrun --arch qwen3-8b --shape decode_32k --multi-pod
@@ -18,6 +17,14 @@ Per cell this prints/records:
 Artifacts land in benchmarks/artifacts/dryrun/<cell>.json and are consumed by
 benchmarks/roofline.py and EXPERIMENTS.md.
 """
+import os
+
+if __name__ == "__main__":
+    os.environ.setdefault("JAX_PLATFORMS", "cpu")
+    os.environ["XLA_FLAGS"] = " ".join(filter(None, (
+        os.environ.get("XLA_FLAGS"),
+        "--xla_force_host_platform_device_count=512")))
+
 import argparse
 import dataclasses
 import json

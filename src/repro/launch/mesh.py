@@ -18,10 +18,18 @@ HBM_BW = 819e9                    # bytes/s per chip
 ICI_BW = 50e9                     # bytes/s per link
 
 
+def make_mesh(shape, axes):
+    """`jax.make_mesh` with every axis `Auto`: GSPMD propagates shardings and
+    `with_sharding_constraint` accepts the axes (`jax.make_mesh` defaults
+    to `Explicit` axes, which it refuses)."""
+    return jax.make_mesh(shape, axes,
+                         axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
+
+
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return make_mesh(shape, axes)
 
 
 def make_local_mesh(model_shards: int = 1, seq_shards: int = 1):
@@ -34,9 +42,8 @@ def make_local_mesh(model_shards: int = 1, seq_shards: int = 1):
     n = len(jax.devices())
     assert n % (model_shards * seq_shards) == 0, (n, model_shards, seq_shards)
     if seq_shards == 1:
-        return jax.make_mesh((n // model_shards, model_shards),
-                             ("data", "model"))
-    return jax.make_mesh(
+        return make_mesh((n // model_shards, model_shards), ("data", "model"))
+    return make_mesh(
         (n // (model_shards * seq_shards), seq_shards, model_shards),
         ("data", "seq", "model"))
 

@@ -73,9 +73,11 @@ def main():
     import jax.numpy as jnp
     from repro.checkpoint import Checkpointer
     from repro.configs import get_config, get_smoke_config
+    from repro.launch.compile_cache import enable_compile_cache
     from repro.models import model as M
     from repro.serving import ServingEngine
 
+    enable_compile_cache()
     cfg = get_smoke_config(args.arch) if args.smoke else get_config(args.arch)
     if args.smoke:
         cfg = dataclasses.replace(cfg, dtype="float32")

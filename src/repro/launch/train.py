@@ -41,9 +41,11 @@ def main():
     from repro.configs import SHAPES_BY_NAME, get_config, get_smoke_config
     from repro.configs.base import OptimizerConfig, TrainConfig
     from repro.launch import mesh as mesh_lib
+    from repro.launch.compile_cache import enable_compile_cache
     from repro.parallel.sharding import ParallelCtx
     from repro.train import Trainer
 
+    enable_compile_cache()
     cfg = get_smoke_config(args.arch) if args.smoke else get_config(args.arch)
     if args.smoke:
         cfg = dataclasses.replace(cfg, dtype="float32")

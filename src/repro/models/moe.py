@@ -21,7 +21,7 @@ from jax.sharding import PartitionSpec as P
 
 from repro.configs.base import MLPConfig, MoEConfig
 from repro.models import layers as L
-from repro.parallel.sharding import ParallelCtx, shard_map as _shard_map
+from repro.parallel.sharding import ParallelCtx
 
 
 
@@ -188,7 +188,7 @@ def _moe_weight_stationary(
         return out, aux
 
     fs = fsdp if fsdp else None
-    out, aux = _shard_map(
+    out, aux = jax.shard_map(
         body, mesh=mesh,
         in_specs=(P(None, None), P(maxis, fs, None),
                   P(maxis, fs, None) if w_gate is not None else P(),
@@ -250,7 +250,7 @@ def apply_moe(
     # Expert weights enter replicated along data axes (in_specs trigger the
     # FSDP all-gather here when params are stored fsdp-sharded).
     gate_spec = P(maxis, None, None) if w_gate is not None else P()
-    out, aux = _shard_map(
+    out, aux = jax.shard_map(
         sharded, mesh=mesh,
         in_specs=(P(None, None), P(maxis, None, None), gate_spec,
                   P(maxis, None, None), P(daxes, None)),

@@ -60,7 +60,7 @@ from repro.kernels import ops as kernel_ops
 from repro.kernels.common import resolve_backend, resolve_backward_impl
 from repro.launch.mesh import (axis_size, validate_attention_mesh,
                                validate_seq_shards)
-from repro.parallel.sharding import ParallelCtx, shard_map as _shard_map
+from repro.parallel.sharding import ParallelCtx
 
 # The axis-name registry: every mesh this stack builds (launch/mesh.py) and
 # every PartitionSpec it writes draws from these four names. repro-lint's
@@ -150,8 +150,8 @@ class AttentionPlan:
         return self.tp_axis if self.tp > 1 else None
 
     def _smap(self, body, in_specs, out_specs):
-        return _shard_map(body, mesh=self.mesh, in_specs=in_specs,
-                          out_specs=out_specs, check_vma=False)
+        return jax.shard_map(body, mesh=self.mesh, in_specs=in_specs,
+                             out_specs=out_specs, check_vma=False)
 
     # -- train fwd/bwd: blockwise-causal (linformer_causal) -----------------
 

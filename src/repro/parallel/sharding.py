@@ -8,43 +8,11 @@ data-like axes too (FSDP / ZeRO-3, per-arch `MeshConfig.fsdp`).
 from __future__ import annotations
 
 import dataclasses
-import inspect
 import re
 from typing import Optional, Sequence, Tuple
 
 import jax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
-
-try:  # jax>=0.6 exposes shard_map at top level
-    from jax import shard_map as _shard_map_impl
-except ImportError:  # pragma: no cover
-    from jax.experimental.shard_map import shard_map as _shard_map_impl
-
-_SM_PARAMS = frozenset(inspect.signature(_shard_map_impl).parameters)
-
-
-def shard_map(f, *, mesh, in_specs, out_specs, check_vma: bool = True,
-              axis_names: Optional[frozenset] = None):
-    """Version-portable ``shard_map`` wrapper.
-
-    Newer JAX spells the replication check ``check_vma`` and partial-manual
-    mode ``axis_names`` (the MANUAL axes); older releases spell them
-    ``check_rep`` and ``auto`` (the complement: axes left to GSPMD). Callers
-    use the new-style keywords; this adapter translates for whichever JAX is
-    installed — the root cause of the seed's test_distributed failures.
-    ``check_vma`` defaults to True, matching upstream.
-    """
-    kw = {}
-    if "check_vma" in _SM_PARAMS:
-        kw["check_vma"] = check_vma
-        if axis_names is not None:
-            kw["axis_names"] = frozenset(axis_names)
-    else:
-        kw["check_rep"] = check_vma
-        if axis_names is not None:
-            kw["auto"] = frozenset(mesh.axis_names) - frozenset(axis_names)
-    return _shard_map_impl(f, mesh=mesh, in_specs=in_specs,
-                           out_specs=out_specs, **kw)
 
 
 @dataclasses.dataclass(frozen=True)

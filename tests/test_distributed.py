@@ -211,7 +211,8 @@ def test_hlo_cost_analyzer_counts_loop_collectives():
         import jax, jax.numpy as jnp
         from jax.sharding import NamedSharding, PartitionSpec as P
         from repro.launch.hlo_cost import analyze_text
-        mesh = jax.make_mesh((8,), ("data",))
+        from repro.launch.mesh import make_mesh
+        mesh = make_mesh((8,), ("data",))
         L, D = 7, 64
 
         def f(ws, x):
@@ -252,10 +253,11 @@ def test_compressed_cross_pod_gradients_track_exact():
         from repro.train.trainer import make_train_step
         from repro.train.compressed_dp import (make_compressed_train_step,
                                                init_residual)
+        from repro.launch.mesh import make_mesh
 
         cfg = dataclasses.replace(get_smoke_config("qwen3-8b"),
                                   dtype="float32")
-        mesh = jax.make_mesh((2, 2, 2), ("pod", "data", "model"))
+        mesh = make_mesh((2, 2, 2), ("pod", "data", "model"))
         ctx = ParallelCtx(mesh=mesh, fsdp="data")
         ocfg = OptimizerConfig(lr=1e-3, warmup_steps=0)
         params = M.init_params(jax.random.PRNGKey(0), cfg)
@@ -287,12 +289,13 @@ def test_trainer_with_compressed_pod_grads_end_to_end():
         import dataclasses, tempfile, jax
         from repro.configs import get_smoke_config
         from repro.configs.base import OptimizerConfig, TrainConfig
+        from repro.launch.mesh import make_mesh
         from repro.parallel.sharding import ParallelCtx
         from repro.train import Trainer
 
         cfg = dataclasses.replace(get_smoke_config("qwen3-8b"),
                                   dtype="float32")
-        mesh = jax.make_mesh((2, 2, 2), ("pod", "data", "model"))
+        mesh = make_mesh((2, 2, 2), ("pod", "data", "model"))
         ctx = ParallelCtx(mesh=mesh, fsdp="none")
         d = tempfile.mkdtemp()
         tcfg = TrainConfig(seq_len=32, global_batch=8, steps=6, log_every=99,

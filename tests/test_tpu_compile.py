@@ -1,0 +1,159 @@
+"""Compile every main-path Pallas kernel for a described TPU v5e.
+
+Nothing runs: each kernel is lowered and compiled by the TPU compiler for a
+`v5e:2x2` topology that is described, not attached, at the published widths
+of the configs that use it (qwen3-8b for the causal, chunk and decode
+forms, linformer-paper for the exact form and the sequence projection) and
+at the largest compressed width the wrappers admit, M = MAX_PINNED_SLOTS.
+This is what interpret-mode tests cannot check: Mosaic's block-shape rules
+and the VMEM budgets.
+
+The topology is described inside a module-scoped fixture, never at import:
+only one process may load the TPU library, and every pytest worker imports
+this file.
+"""
+import os
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+from repro.configs import get_config
+from repro.kernels import blockwise_causal_attn as bca
+from repro.kernels import linformer_attn as la
+from repro.kernels import seq_projection as sp
+from repro.kernels.common import MAX_PINNED_SLOTS
+
+BF16, F32, I8, I32 = jnp.bfloat16, jnp.float32, jnp.int8, jnp.int32
+
+
+@pytest.fixture(scope="module")
+def one_chip():
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    try:
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:  # no TPU compiler in this installation
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    # a compile for a described chip is written to the persistent cache but
+    # cannot be read back without one: keep these compiles out of it
+    enabled = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield SingleDeviceSharding(topo.devices[0])
+    jax.config.update("jax_enable_compilation_cache", enabled)
+
+
+def _causal_dims():
+    a = get_config("qwen3-8b").attention
+    return dict(H=a.num_heads, Hkv=a.num_kv_heads, Dh=a.head_dim,
+                c=a.linformer.block_size, r=a.linformer.block_slots)
+
+
+def _decode(d, B=2, M=MAX_PINNED_SLOTS):
+    H, Hkv, Dh, c = d["H"], d["Hkv"], d["Dh"], d["c"]
+    G = H // Hkv
+    fn = lambda *x: la.decode_attn(*x, scale=Dh ** -0.5)
+    return fn, [((B, Hkv, G, Dh), BF16), ((B, Hkv, c, Dh), BF16),
+                ((B, Hkv, c, Dh), BF16), ((B, Hkv, M, Dh), BF16),
+                ((B, Hkv, M, Dh), BF16), ((B, c), F32), ((B, M), F32)]
+
+
+def _decode_q(d, B=2, M=MAX_PINNED_SLOTS):
+    H, Hkv, Dh, c = d["H"], d["Hkv"], d["Dh"], d["c"]
+    G = H // Hkv
+    fn = lambda *x: la.decode_attn_q(*x, scale=Dh ** -0.5)
+    return fn, [((B, Hkv, G, Dh), BF16), ((B, Hkv, c, Dh), I8),
+                ((B, Hkv, c, Dh), I8), ((B, Hkv, M, Dh), I8),
+                ((B, Hkv, M, Dh), I8), ((B, Hkv, c), F32),
+                ((B, Hkv, c), F32), ((B, Hkv, M), F32), ((B, Hkv, M), F32),
+                ((B, c), F32), ((B, M), F32)]
+
+
+def _prefix(d, residuals=False, B=2, P=512, M=MAX_PINNED_SLOTS):
+    H, Hkv, Dh, c, r = (d[k] for k in ("H", "Hkv", "Dh", "c", "r"))
+    fn = lambda *x: bca.blockwise_causal_prefix_attn(
+        *x, block_size=c, block_slots=r, scale=Dh ** -0.5,
+        return_residuals=residuals)
+    return fn, [((B, H, P, Dh), BF16), ((B, Hkv, P, Dh), BF16),
+                ((B, Hkv, P, Dh), BF16), ((B, Hkv, M, Dh), BF16),
+                ((B, Hkv, M, Dh), BF16), ((B,), I32)]
+
+
+def _prefix_q(d, B=2, P=512, M=MAX_PINNED_SLOTS):
+    H, Hkv, Dh, c, r = (d[k] for k in ("H", "Hkv", "Dh", "c", "r"))
+    fn = lambda *x: bca.blockwise_causal_prefix_attn_q(
+        *x, block_size=c, block_slots=r, scale=Dh ** -0.5)
+    return fn, [((B, H, P, Dh), BF16), ((B, Hkv, P, Dh), BF16),
+                ((B, Hkv, P, Dh), BF16), ((B, Hkv, M, Dh), I8),
+                ((B, Hkv, M, Dh), I8), ((B, Hkv, M), F32),
+                ((B, Hkv, M), F32), ((B,), I32)]
+
+
+def _causal_shapes(d, M=MAX_PINNED_SLOTS):
+    H, Hkv, Dh, c, r = (d[k] for k in ("H", "Hkv", "Dh", "c", "r"))
+    S = (M // r) * c
+    return S, [((1, H, S, Dh), BF16), ((1, Hkv, S, Dh), BF16),
+               ((1, Hkv, S, Dh), BF16), ((1, Hkv, M, Dh), BF16),
+               ((1, Hkv, M, Dh), BF16)]
+
+
+def _causal(d, residuals=False):
+    _, shapes = _causal_shapes(d)
+    fn = lambda *x: bca.blockwise_causal_attn(
+        *x, block_size=d["c"], block_slots=d["r"], scale=d["Dh"] ** -0.5,
+        return_residuals=residuals)
+    return fn, shapes
+
+
+def _causal_bwd(d):
+    S, shapes = _causal_shapes(d)
+    fn = lambda *x: bca.blockwise_causal_attn_bwd(
+        *x, block_size=d["c"], block_slots=d["r"], scale=d["Dh"] ** -0.5)
+    return fn, shapes + [((1, d["H"], S), F32), ((1, d["H"], S), F32),
+                         shapes[0]]
+
+
+def _paper_dims(B=8):
+    cfg = get_config("linformer-paper")
+    a = cfg.attention
+    return B, a.num_heads, cfg.max_seq_len, a.linformer.k, a.head_dim
+
+
+def _exact(_):
+    B, H, n, k, Dh = _paper_dims()
+    fn = lambda *x: la.linformer_attn(*x, scale=Dh ** -0.5, block_q=256)
+    return fn, [((B, H, n, Dh), BF16), ((B, H, k, Dh), BF16),
+                ((B, H, k, Dh), BF16)]
+
+
+def _seq_projection(_):
+    B, H, n, k, Dh = _paper_dims()
+    fn = lambda *x: sp.seq_projection(*x, block_s=n)
+    return fn, [((B, H, n, Dh), BF16), ((n, k), BF16)]
+
+
+CASES = {
+    "decode_attn": _decode,
+    "decode_attn_q": _decode_q,
+    "chunk_prefill": _prefix,
+    "chunk_prefill_residuals": lambda d: _prefix(d, residuals=True),
+    "chunk_prefill_q": _prefix_q,
+    "causal_forward": _causal,
+    "causal_forward_residuals": lambda d: _causal(d, residuals=True),
+    "causal_backward": _causal_bwd,
+    "exact_form": _exact,
+    "seq_projection": _seq_projection,
+}
+
+
+@pytest.mark.parametrize("name", list(CASES))
+def test_kernel_compiles_for_v5e(one_chip, name):
+    fn, shapes = CASES[name](_causal_dims())
+    args = [jax.ShapeDtypeStruct(s, dt, sharding=one_chip)
+            for s, dt in shapes]
+    compiled = jax.jit(fn).lower(*args).compile()
+    assert "tpu_custom_call" in compiled.as_text()
