@@ -616,7 +616,7 @@ class Scheduler:
         self.shed: Dict[int, ShedResult] = {}
         self.completed_at: Dict[int, int] = {}      # rid -> completion tick
         self.snapshots: Dict[int, SlotSnapshot] = {}  # row -> last good
-        self._streamed: Dict[int, int] = {}  # rid -> on_token high-water
+        self._streamed: Dict[int, int] = {}  # rid -> streamed high-water
         #                                      mark (a requeued request must
         #                                      not re-stream tokens)
         self._seq = 0
@@ -844,9 +844,12 @@ class Scheduler:
                 n_valid[j] = n
             # repro-lint: allow[RL002] n_valid is a host staging buffer
             chunk_tokens = int(n_valid.sum())
-            with self.telemetry.span("prefill_chunk_forward",
-                                     cat="scheduler", rows=g,
-                                     tokens=chunk_tokens):
+            with self.telemetry.span(
+                    "prefill_chunk_forward", cat="scheduler", rows=g,
+                    tokens=chunk_tokens,
+                    rids=[s.request.rid for _, s, _ in chunk_rows],
+                    chunks=[f"{s.filled}:{n}" for (_, s, _), n
+                            in zip(chunk_rows, n_valid.tolist())]):
                 logits = self.pool.prefill_chunk_rows(
                     [row for row, _, _ in chunk_rows], toks, n_valid)
             self.stats.prefill_forwards += 1
@@ -872,7 +875,8 @@ class Scheduler:
                  for _, s in group], np.int32)
             with self.telemetry.span("prefill_remainder_forward",
                                      cat="scheduler", rows=len(group),
-                                     tokens=rem * len(group)):
+                                     tokens=rem * len(group),
+                                     rids=[s.request.rid for _, s in group]):
                 logits = self.pool.prefill_remainder_rows(
                     [row for row, _ in group], toks)
             self.stats.prefill_forwards += 1
@@ -1011,7 +1015,8 @@ class Scheduler:
                      results: Dict[int, List[int]]) -> None:
         """Distribute a chunk's tokens to their requests; retire EOS'd /
         budget-exhausted slots. A requeued request's already-streamed
-        tokens are not re-streamed (`_streamed` high-water mark)."""
+        tokens are not re-streamed (`_streamed` high-water mark). A
+        request's first streamed token is stamped `first_streamed`."""
         for row in range(self.pool.max_batch):
             slot = self.pool.slots[row]
             if slot is None or slot.state != DECODING:
@@ -1025,10 +1030,14 @@ class Scheduler:
                     done = True
                     break
                 slot.emitted.append(tok)
-                if on_token is not None \
-                        and len(slot.emitted) > self._streamed.get(rid, 0):
-                    self._streamed[rid] = len(slot.emitted)
-                    on_token(rid, tok)
+                n = len(slot.emitted)
+                if n > self._streamed.get(rid, 0):
+                    self._streamed[rid] = n
+                    if n == 1:
+                        self.timelines.stamp(rid, "first_streamed",
+                                             self.stats.ticks)
+                    if on_token is not None:
+                        on_token(rid, tok)
             if len(slot.emitted) >= budget:
                 done = True
             if done:
@@ -1059,6 +1068,13 @@ class Scheduler:
         results: Dict[int, object] = {}
         chunk = self.engine.decode_chunk
         while self.waiting or self.pool.occupancy:
+            # a mark, not a span: callers' telemetry may act on span exits
+            # (the benchmark submits due arrivals there)
+            decoding = self.pool.decoding_count
+            self.telemetry.instant(
+                "scheduler_round", cat="scheduler",
+                waiting=len(self.waiting),
+                prefilling=self.pool.occupancy - decoding, decoding=decoding)
             self._admit_ready()
             if self.engine.prefill_chunk:
                 self._advance_prefill()
@@ -1100,9 +1116,11 @@ class Scheduler:
                                                  self.stats.chunks)
             # one span per chunk, closed at the chunk's single host sync —
             # stamping here adds ZERO device syncs (the sync already exists)
-            with self.telemetry.span("decode_chunk", cat="scheduler",
-                                     rows=decoding, chunk=chunk,
-                                     tick=self.stats.ticks):
+            with self.telemetry.span(
+                    "decode_chunk", cat="scheduler", rows=decoding,
+                    chunk=chunk, tick=self.stats.ticks,
+                    rids=[s.request.rid for s in self.pool.slots
+                          if s is not None and s.state == DECODING]):
                 toks, bad, self.rng = self.pool.decode_chunk(chunk, self.rng)
             faulted = self._collect_faults(bad)
             self.stats.chunks += 1

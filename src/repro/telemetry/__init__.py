@@ -19,8 +19,13 @@ every run back together at export time (one Perfetto process per run).
 
 Disabled contract: `Telemetry(enabled=False)` — and the module-level
 `NULL_TELEMETRY` singleton — makes every hot-path call a no-op without
-call sites branching: `span()` returns the null span, `new_timelines()`
-returns the shared `NULL_TIMELINES`, `record()` returns immediately.
+call sites branching, and allocates nothing unless a profiler session is
+capturing: `span()` returns the null span, `new_timelines()` returns the
+shared `NULL_TIMELINES`, `record()` returns immediately.
+
+Profiler sink: while a JAX profiler session captures (`jax.profiler.
+trace`), spans, instants and lifecycle stamps also go into the profiler's
+trace as `TraceAnnotation`s, enabled facade or not (trace.py).
 """
 from __future__ import annotations
 

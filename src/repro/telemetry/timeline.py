@@ -3,10 +3,14 @@
 Every request admitted to the continuous-batching scheduler moves through
 a small state machine (docs/serving.md):
 
-    queued -> admitted -> prefilling (per chunk) -> decoding (per chunk)
+    queued -> admitted -> prefilling (per chunk) -> first_token
+           -> first_streamed -> decoding (per chunk)
            -> preempted/snapshotted -> requeued -> ... -> retired
            |  shed (queue_full | deadline_infeasible | retries_exhausted)
            |  quarantined (fault)
+
+(`first_token` is the first token sampled; `first_streamed` is the drain
+of the decode chunk after it, which hands that token to the caller.)
 
 `ServingTimelines.stamp()` records each transition **at the existing
 per-chunk host sync** — the scheduler already returns to Python between
@@ -31,6 +35,7 @@ from __future__ import annotations
 from typing import Dict, List, Optional, Tuple
 
 from .metrics import MetricsRegistry, TICK_BUCKETS, MS_BUCKETS
+from .trace import capturing, mark
 
 # Events that OPEN a phase bar (value = bar name), and events that CLOSE
 # whatever bar is open. Everything stamped also gets an instant marker.
@@ -45,14 +50,18 @@ _PHASE_ENDS = frozenset({"retired", "shed", "quarantined"})
 
 
 class NullTimelines:
-    """Disabled-telemetry stand-in: `stamp` is a no-op, `finalize` too.
-    Shares the scheduler-facing surface so call sites stay unconditional."""
+    """Disabled-telemetry stand-in: `stamp` keeps nothing and `finalize`
+    is a no-op. While a profiler session is capturing, `stamp` still
+    writes its `request_<event>` mark, so a disabled facade's lifecycle
+    reaches the profiler's trace. Shares the scheduler-facing surface so
+    call sites stay unconditional."""
 
     __slots__ = ()
     enabled = False
 
     def stamp(self, rid, event, tick, **fields):
-        pass
+        if capturing():
+            mark(f"request_{event}", rid=rid, tick=tick, **fields)
 
     def finalize(self, registry=None):
         pass
@@ -79,11 +88,15 @@ class ServingTimelines:
     # -- recording ---------------------------------------------------------
 
     def stamp(self, rid: int, event: str, tick: int, **fields) -> None:
+        """Record one transition; its instant (and, while a profiler
+        session captures, its one mark) is `request_<event>`."""
         t_us = None
         if self._tracer is not None and self._tracer.enabled:
             t_us = self._tracer._now_us()
             self._tracer.instant(f"request_{event}", cat="request",
                                  rid=rid, tick=tick, **fields)
+        elif capturing():
+            mark(f"request_{event}", rid=rid, tick=tick, **fields)
         self._stamps.setdefault(rid, []).append((event, tick, t_us, fields))
 
     def stamps(self, rid: int) -> List[Tuple[str, int, Optional[float], Dict]]:

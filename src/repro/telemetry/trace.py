@@ -13,12 +13,21 @@ Contract (docs/observability.md §Overhead contract):
   shared state, so concurrently open spans from different threads are
   fine. The exported events carry the OS thread id, so Perfetto renders
   one track per thread.
-* **Disabled = no-op** — a disabled tracer's ``span()`` returns a single
-  module-level ``_NULL_SPAN`` object (no allocation, no clock read, no
-  lock) and ``instant()`` returns immediately. The decode hot path can
-  therefore keep its instrumentation calls unconditionally; with
-  telemetry off they cost one attribute load and one branch
+* **Disabled = no-op** — unless a profiler session is capturing, a
+  disabled tracer's ``span()`` returns a single module-level
+  ``_NULL_SPAN`` object (no allocation, no clock read, no lock) and
+  ``instant()`` returns immediately. The decode hot path can therefore
+  keep its instrumentation calls unconditionally; with telemetry off they
+  cost one attribute load, one branch and one ``is_enabled()`` check
   (negative-tested in tests/test_telemetry.py).
+* **Profiler sink** — while a JAX profiler session is capturing
+  (``jax.profiler.trace`` / ``start_trace``), every span also opens a
+  ``jax.profiler.TraceAnnotation`` carrying its args, and every instant
+  writes a zero-length one (a *mark*), enabled tracer or not. They land
+  on the host plane of the profiler's trace, on the same clock as the
+  device operations. Arg values are encoded by ``encode_arg``: no ``,``,
+  ``#`` or ``=`` (the annotation format's separators), lists joined with
+  spaces.
 
 Export is the Chrome trace-event JSON array format (``{"traceEvents":
 [...]}``) that both ``chrome://tracing`` and https://ui.perfetto.dev load
@@ -32,7 +41,36 @@ import threading
 import time
 from typing import Callable, Dict, List, Optional
 
+from jax.profiler import TraceAnnotation
+
 HOST_PID = 0            # pid of the host-side scheduler/engine/trainer track
+
+# True while a profiler session is capturing (one C++ call)
+capturing = TraceAnnotation.is_enabled
+
+_ARG_SEPARATORS = str.maketrans({",": ";", "#": ";", "=": ":"})
+
+
+def encode_arg(value) -> str:
+    """An annotation arg value: lists and tuples joined with spaces, the
+    annotation format's separators (`,` `#` `=`) replaced."""
+    s = (" ".join(map(str, value)) if isinstance(value, (list, tuple))
+         else str(value))
+    if "," in s or "#" in s or "=" in s:
+        return s.translate(_ARG_SEPARATORS)
+    return s
+
+
+def _annotation(name: str, args) -> TraceAnnotation:
+    return TraceAnnotation(name, **{k: encode_arg(v)
+                                    for k, v in (args or {}).items()})
+
+
+def mark(name: str, **args) -> None:
+    """A zero-length annotation in the profiler's trace; call it only
+    while a session is `capturing()`."""
+    with _annotation(name, args):
+        pass
 
 
 class _NullSpan:
@@ -54,23 +92,51 @@ class _NullSpan:
 _NULL_SPAN = _NullSpan()
 
 
-class _Span:
-    """An open span: records [enter, exit) as one complete event."""
+class _ProfilerSpan:
+    """A disabled tracer's span while a profiler session is capturing:
+    only the profiler annotation."""
 
-    __slots__ = ("_tracer", "_name", "_cat", "_args", "_t0")
+    __slots__ = ("_ann",)
+
+    def __init__(self, name: str, args):
+        self._ann = _annotation(name, args)
+
+    def __enter__(self):
+        self._ann.__enter__()
+        return self
+
+    def __exit__(self, *exc):
+        self._ann.__exit__(*exc)
+        return False
+
+    def annotate(self, **args):
+        self._ann.set_metadata(**{k: encode_arg(v) for k, v in args.items()})
+        return self
+
+
+class _Span:
+    """An open span: records [enter, exit) as one complete event, and is
+    a profiler annotation too while a session is capturing."""
+
+    __slots__ = ("_tracer", "_name", "_cat", "_args", "_t0", "_ann")
 
     def __init__(self, tracer: "Tracer", name: str, cat: str, args):
         self._tracer = tracer
         self._name = name
         self._cat = cat
         self._args = args
+        self._ann = _ProfilerSpan(name, args) if capturing() else None
 
     def __enter__(self):
+        if self._ann is not None:
+            self._ann.__enter__()
         self._t0 = self._tracer._now_us()
         return self
 
     def __exit__(self, *exc):
         t1 = self._tracer._now_us()
+        if self._ann is not None:
+            self._ann.__exit__(*exc)
         self._tracer._push(("X", self._name, self._cat, self._t0,
                             t1 - self._t0, threading.get_ident(),
                             self._args or None))
@@ -82,6 +148,8 @@ class _Span:
         if self._args is None:
             self._args = {}
         self._args.update(args)
+        if self._ann is not None:
+            self._ann.annotate(**args)
         return self
 
 
@@ -116,13 +184,17 @@ class Tracer:
 
     def span(self, name: str, cat: str = "span", **args):
         """Context manager timing a host-side region. Disabled tracers
-        return the no-op singleton — zero allocation on the hot path."""
+        return the no-op singleton — zero allocation on the hot path —
+        unless a profiler session is capturing."""
         if not self.enabled:
-            return _NULL_SPAN
+            return _ProfilerSpan(name, args) if capturing() else _NULL_SPAN
         return _Span(self, name, cat, args or None)
 
     def instant(self, name: str, cat: str = "event", **args) -> None:
-        """A point-in-time marker (rendered as an arrow/flag in Perfetto)."""
+        """A point-in-time marker (rendered as an arrow/flag in Perfetto,
+        and a mark in the profiler's trace while a session captures)."""
+        if capturing():
+            mark(name, **args)
         if not self.enabled:
             return
         self._push(("i", name, cat, self._now_us(), 0,

@@ -6,13 +6,16 @@ of the configs that use it (qwen3-8b for the causal, chunk and decode
 forms, linformer-paper for the exact form and the sequence projection) and
 at the largest compressed width the wrappers admit, M = MAX_PINNED_SLOTS.
 This is what interpret-mode tests cannot check: Mosaic's block-shape rules
-and the VMEM budgets.
+and the VMEM budgets. The kernels the benchmark's trace readers find by
+name are compiled through their `kernels/ops.py` wrappers too, and each
+must come out as an operation of that name.
 
 The topology is described inside a module-scoped fixture, never at import:
 only one process may load the TPU library, and every pytest worker imports
 this file.
 """
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -22,6 +25,7 @@ from jax.sharding import SingleDeviceSharding
 from repro.configs import get_config
 from repro.kernels import blockwise_causal_attn as bca
 from repro.kernels import linformer_attn as la
+from repro.kernels import ops
 from repro.kernels import seq_projection as sp
 from repro.kernels.common import MAX_PINNED_SLOTS
 
@@ -157,3 +161,56 @@ def test_kernel_compiles_for_v5e(one_chip, name):
             for s, dt in shapes]
     compiled = jax.jit(fn).lower(*args).compile()
     assert "tpu_custom_call" in compiled.as_text()
+
+
+def _op_decode(d, B=2, M=MAX_PINNED_SLOTS):
+    H, Hkv, Dh, c = d["H"], d["Hkv"], d["Dh"], d["c"]
+    fn = lambda *x: ops.fused_decode_attention(*x, scale=Dh ** -0.5,
+                                               interpret=False)
+    return fn, [((B, 1, H, Dh), BF16), ((B, c, Hkv, Dh), BF16),
+                ((B, c, Hkv, Dh), BF16), ((B, M, Hkv, Dh), BF16),
+                ((B, M, Hkv, Dh), BF16), ((B, c), F32), ((B, M), F32)]
+
+
+def _op_chunk_prefill(d, B=2, P=512, M=MAX_PINNED_SLOTS):
+    H, Hkv, Dh, c, r = (d[k] for k in ("H", "Hkv", "Dh", "c", "r"))
+    fn = lambda *x: ops.fused_chunk_prefill_attention(
+        *x, block_size=c, block_slots=r, scale=Dh ** -0.5, interpret=False)
+    return fn, [((B, P, H, Dh), BF16), ((B, P, Hkv, Dh), BF16),
+                ((B, P, Hkv, Dh), BF16), ((B, M, Hkv, Dh), BF16),
+                ((B, M, Hkv, Dh), BF16), ((B,), I32)]
+
+
+def _op_exact(_):
+    B, H, n, k, Dh = _paper_dims()
+    fn = lambda *x: ops.fused_linformer_attention(*x, scale=Dh ** -0.5,
+                                                  interpret=False)
+    return fn, [((B, n, H, Dh), BF16), ((B, k, H, Dh), BF16),
+                ((B, k, H, Dh), BF16)]
+
+
+def _op_seq_projection(_):
+    B, H, n, k, Dh = _paper_dims()
+    fn = lambda *x: ops.fused_seq_projection(*x, interpret=False)
+    return fn, [((B, n, H, Dh), BF16), ((n, k), BF16)]
+
+
+# the operation names bench/metrics/*.py match in a device trace
+READER_OPS = {
+    "fused_decode_attention": _op_decode,
+    "fused_chunk_prefill_attention": _op_chunk_prefill,
+    "fused_linformer_attention": _op_exact,
+    "fused_seq_projection": _op_seq_projection,
+}
+
+
+@pytest.mark.parametrize("op", list(READER_OPS))
+def test_kernel_op_keeps_the_name_readers_match(one_chip, op):
+    fn, shapes = READER_OPS[op](_causal_dims())
+    args = [jax.ShapeDtypeStruct(s, dt, sharding=one_chip)
+            for s, dt in shapes]
+    text = jax.jit(fn).lower(*args).compile().as_text()
+    kernels = [line.strip().split(" = ", 1)[0] for line in text.splitlines()
+               if 'custom_call_target="tpu_custom_call"' in line]
+    assert kernels and all(re.fullmatch(rf"%{op}(\.\d+)?", k)
+                           for k in kernels), kernels
