@@ -11,8 +11,14 @@ Two cache families:
 * :func:`init_full_cache` — the standard-attention baseline: full (S, Hkv, Dh)
   K/V per layer.
 
-Caches are plain dicts of arrays (pytrees); layer axis leads so scanned layers
-carry their slice through ``lax.scan``.
+Caches are plain dicts of arrays (pytrees) with the layer axis leading. The
+per-layer step functions below read one layer's view (the leaf without its
+layer axis) and return ``(out, writes)``: a :class:`SlotWrite` per leaf they
+change, naming only the slots that change (a decode step's token, a completed
+block's r slots; a prefill chunk's slots). :func:`write_cache` applies them —
+to the stacked cache at a layer index (models/transformer.py carries the stack
+through its layer scan, so a donated pool is updated in place and nothing
+rewrites a whole layer buffer), or to a lone layer view.
 
 Per-row positions: the cache carries a ``lengths`` (B,) int32 vector — one
 position counter per batch row — instead of a shared scalar. Every row of a
@@ -32,7 +38,7 @@ between decode chunks.
 """
 from __future__ import annotations
 
-from typing import Dict, Optional, Tuple
+from typing import Dict, NamedTuple, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -48,12 +54,51 @@ def rowwise_t(t: jax.Array, batch: int) -> jax.Array:
     return t
 
 
-def _row_update(buf: jax.Array, new: jax.Array, start: jax.Array) -> jax.Array:
-    """Per-row dynamic_update_slice along axis 1: buf (B, N, ...), new
-    (B, n, ...), start (B,) int32 — row b gets new[b] written at start[b]."""
-    return jax.vmap(
-        lambda b, u, s: jax.lax.dynamic_update_slice_in_dim(b, u, s, axis=0)
-    )(buf, new, start)
+class SlotWrite(NamedTuple):
+    """The slots one step writes into one cache leaf. Update u puts
+    ``value[u]`` (n, *rest) at ``[a0[u], a1[u]:a1[u] + n]`` of the leaf's
+    per-layer buffer (A0, A1, *rest): a0 is the batch row (the arena page
+    for page leaves), a1 the first slot. The slot start clamps to A1 - n, as
+    dynamic_update_slice's does; updates apply in order. Where ``commit[u]``
+    is false, update u writes back the slots' current contents instead."""
+    a0: jax.Array             # (U,) int32
+    a1: jax.Array             # (U,) int32
+    value: jax.Array          # (U, n, *rest)
+    commit: Optional[jax.Array] = None   # (U,) bool; None commits all
+
+
+def row_write(start: jax.Array, value: jax.Array,
+              commit: Optional[jax.Array] = None) -> SlotWrite:
+    """Row b writes value[b] (n, *rest) at slot start[b]."""
+    return SlotWrite(jnp.arange(value.shape[0], dtype=jnp.int32), start,
+                     value, commit)
+
+
+def write_slots(buf: jax.Array, w: SlotWrite, *lead) -> jax.Array:
+    """``buf`` (*L, A0, A1, *rest) with ``w``'s updates written at leading
+    index ``lead`` (the layer of a stacked leaf; none for a layer view): one
+    dynamic_update_slice per update, after a dynamic_slice of the same
+    window where ``w`` has a commit mask. A buffer dead afterwards (a donated
+    pool, a scan carry) is updated in place, and only the written slots
+    move."""
+    value = w.value.astype(buf.dtype)
+    zeros = (0,) * (value.ndim - 2)
+    for u in range(value.shape[0]):
+        start = (*lead, w.a0[u], w.a1[u], *zeros)
+        upd = value[u].reshape((1,) * (len(lead) + 1) + value.shape[1:])
+        if w.commit is not None:
+            cur = jax.lax.dynamic_slice(buf, start, upd.shape)
+            upd = jnp.where(w.commit[u], upd, cur)
+        buf = jax.lax.dynamic_update_slice(buf, upd, start)
+    return buf
+
+
+def write_cache(cache: Dict[str, jax.Array], writes: Dict[str, SlotWrite],
+                *lead) -> Dict[str, jax.Array]:
+    """Apply a step's ``writes`` to the leaves of ``cache`` they name (see
+    :func:`write_slots`); every other leaf passes through untouched."""
+    return {k: write_slots(v, writes[k], *lead) if k in writes else v
+            for k, v in cache.items()}
 
 
 # ---------------------------------------------------------------------------
@@ -86,14 +131,14 @@ def compressed_decode_attention(
     q_t: jax.Array,           # (B, 1, H, Dh) — rope already applied at pos t
     k_t: jax.Array,           # (B, 1, Hkv, Dh)
     v_t: jax.Array,
-    layer_cache: Dict[str, jax.Array],   # per-layer slices: raw_k (B,c,Hkv,Dh), comp_k (B,M,Hkv,Dh)
+    layer_cache: Dict[str, jax.Array],   # layer view: raw_k (B,c,Hkv,Dh), comp_k (B,M,Hkv,Dh)
     E: jax.Array,             # (c, r) or (Hkv, c, r)
     F: jax.Array,
     t: jax.Array,             # () or (B,) int32 — tokens already cached per row
     *,
     scale: Optional[float] = None,
     plan=None,                # AttentionPlan | backend string | None
-) -> Tuple[jax.Array, Dict[str, jax.Array]]:
+) -> Tuple[jax.Array, Dict[str, SlotWrite]]:
     """One decode step of blockwise-causal Linformer attention.
 
     Appends (k_t, v_t) at each row's position t[b], attends [raw block ≤ t[b]
@@ -101,7 +146,11 @@ def compressed_decode_attention(
     slots when t[b] completes it. Every mask, ring-buffer write and block
     fold is PER ROW — rows of a continuous batch sit at unequal positions.
     A scalar t broadcasts to all rows (the legacy shared-position form).
-    Returns (out (B,1,H,Dh), updated per-layer cache).
+
+    Returns (out (B,1,H,Dh), writes): per row, the ring token at t mod c
+    (``raw_k``/``raw_v``) and the r slots at (t // c)·r (``comp_k``/
+    ``comp_v``) — the fold where t completes the block, else the slots as
+    they were — to be applied with :func:`write_cache`.
 
     The attention math itself dispatches through `plan`
     (parallel/plan.py AttentionPlan; a bare backend string resolves to a
@@ -124,8 +173,10 @@ def compressed_decode_attention(
     pos = jnp.mod(t, c)                         # (B,)
     blk = t // c                                # (B,)
 
-    raw_k = _row_update(raw_k, k_t.astype(raw_k.dtype), pos)
-    raw_v = _row_update(raw_v, v_t.astype(raw_v.dtype), pos)
+    writes = {"raw_k": row_write(pos, k_t.astype(raw_k.dtype)),
+              "raw_v": row_write(pos, v_t.astype(raw_v.dtype))}
+    raw_k = write_slots(raw_k, writes["raw_k"])
+    raw_v = write_slots(raw_v, writes["raw_v"])
 
     loc_ok = jnp.arange(c)[None, :] <= pos[:, None]         # (B, c)
     glob_ok = jnp.arange(M)[None, :] < (blk * r)[:, None]   # (B, M)
@@ -133,22 +184,19 @@ def compressed_decode_attention(
                                 loc_ok, glob_ok, scale=scale_)
 
     # fold a row's block into its compressed slots when it completes
-    # (pos[b] == c-1). Compute unconditionally (O(c·r·Dh·Hkv), tiny) and
-    # commit per row via select — cheaper than lax.cond's control flow.
+    # (pos[b] == c-1): computed for every row, committed per row by a select
+    # at slot size between the fold and the slots' current contents, read
+    # where they are written (write_slots).
     if E.ndim == 2:
         new_ks = jnp.einsum("bchd,cr->brhd", raw_k, E.astype(raw_k.dtype))
         new_vs = jnp.einsum("bchd,cr->brhd", raw_v, F.astype(raw_v.dtype))
     else:
         new_ks = jnp.einsum("bchd,hcr->brhd", raw_k, E.astype(raw_k.dtype))
         new_vs = jnp.einsum("bchd,hcr->brhd", raw_v, F.astype(raw_v.dtype))
-    done = (pos == (c - 1))[:, None, None, None]
-    comp_k_new = _row_update(comp_k, new_ks, blk * r)
-    comp_v_new = _row_update(comp_v, new_vs, blk * r)
-    comp_k = jnp.where(done, comp_k_new, comp_k)
-    comp_v = jnp.where(done, comp_v_new, comp_v)
-
-    return out, {"raw_k": raw_k, "raw_v": raw_v,
-                 "comp_k": comp_k, "comp_v": comp_v}
+    done = pos == (c - 1)
+    writes["comp_k"] = row_write(blk * r, new_ks, done)
+    writes["comp_v"] = row_write(blk * r, new_vs, done)
+    return out, writes
 
 
 def compressed_prefill_chunk(
@@ -162,7 +210,7 @@ def compressed_prefill_chunk(
     *,
     scale: Optional[float] = None,
     plan=None,                # AttentionPlan | backend string | None
-) -> Tuple[jax.Array, Dict[str, jax.Array]]:
+) -> Tuple[jax.Array, Dict[str, SlotWrite]]:
     """One chunked-prefill step of blockwise-causal Linformer attention.
 
     Mid-prefill cache write at an arbitrary PER-ROW offset: row b's chunk
@@ -186,14 +234,14 @@ def compressed_prefill_chunk(
     are overwritten by the next chunk or by the decode-time block fold before
     visibility reaches them, so no masking of the write is needed.
 
-    Returns (out (B, P, H, Dh), updated per-layer cache).
+    Returns (out (B, P, H, Dh), writes): each row's P/c·r slots at
+    (t0[b] // c)·r in ``comp_k``/``comp_v`` (see :func:`write_cache`).
     """
     from repro.parallel.plan import as_plan
     plan = as_plan(plan)
-    raw_k, raw_v = layer_cache["raw_k"], layer_cache["raw_v"]
     comp_k, comp_v = layer_cache["comp_k"], layer_cache["comp_v"]
     B, P, Hkv, Dh = k.shape
-    c = raw_k.shape[1]
+    c = layer_cache["raw_k"].shape[1]
     r = E.shape[-1]
     scale_ = scale if scale is not None else q.shape[-1] ** -0.5
     if P % c != 0:
@@ -205,17 +253,19 @@ def compressed_prefill_chunk(
     vbar = compress_blocks(v.reshape(B, nb, c, Hkv, Dh), F)
     t0 = rowwise_t(t0, B)
     slot0 = (t0 // c) * r
-    comp_k = _row_update(comp_k, kbar.reshape(B, nb * r, Hkv, Dh)
-                         .astype(comp_k.dtype), slot0)
-    comp_v = _row_update(comp_v, vbar.reshape(B, nb * r, Hkv, Dh)
-                         .astype(comp_v.dtype), slot0)
+    writes = {
+        "comp_k": row_write(slot0, kbar.reshape(B, nb * r, Hkv, Dh)
+                            .astype(comp_k.dtype)),
+        "comp_v": row_write(slot0, vbar.reshape(B, nb * r, Hkv, Dh)
+                            .astype(comp_v.dtype))}
+    comp_k = write_slots(comp_k, writes["comp_k"])
+    comp_v = write_slots(comp_v, writes["comp_v"])
 
     start_blocks = t0 // c
     out = plan.chunk_prefill_attention(
         q, k, v, comp_k, comp_v, start_blocks,
         block_size=c, block_slots=r, scale=scale_)
-    return out, {"raw_k": raw_k, "raw_v": raw_v,
-                 "comp_k": comp_k, "comp_v": comp_v}
+    return out, writes
 
 
 # ---------------------------------------------------------------------------
@@ -241,8 +291,8 @@ def compressed_prefill_chunk(
 #   blocks only) and snapshots slice to the row's valid page count.
 #
 # The page_table leaf carries a leading layer axis like every other leaf
-# (broadcast-identical rows) purely so it scans through the per-layer
-# ``lax.scan`` in transformer.py unchanged.
+# (broadcast-identical rows) purely so each layer's view has one; no step
+# writes it.
 
 
 def resolve_page_dtype(name: str = "int8"):
@@ -353,6 +403,16 @@ def paged_gather(page_q: jax.Array, page_s: jax.Array,
     return gq, gs
 
 
+def _page_writes(dst, k_q, v_q, k_s, v_s) -> Dict[str, SlotWrite]:
+    """Whole arena pages: update u writes page dst[u] — payload (r, Hkv,
+    Dh) and scales (Hkv,)."""
+    zero = jnp.zeros_like(dst)
+    return {"page_k": SlotWrite(dst, zero, k_q),
+            "page_v": SlotWrite(dst, zero, v_q),
+            "page_k_s": SlotWrite(dst, zero, k_s),
+            "page_v_s": SlotWrite(dst, zero, v_s)}
+
+
 def paged_decode_attention(
     q_t: jax.Array,           # (B, 1, H, Dh) — rope already applied at pos t
     k_t: jax.Array,           # (B, 1, Hkv, Dh)
@@ -364,7 +424,7 @@ def paged_decode_attention(
     *,
     scale: Optional[float] = None,
     plan=None,                # AttentionPlan | backend string | None
-) -> Tuple[jax.Array, Dict[str, jax.Array]]:
+) -> Tuple[jax.Array, Dict[str, SlotWrite]]:
     """One decode step over the paged, quantized cache.
 
     Identical bookkeeping to :func:`compressed_decode_attention` with three
@@ -375,15 +435,16 @@ def paged_decode_attention(
     re-quantized per (row, head) over (r, Dh) and scattered to the row's
     table page — rows that did not complete a block, or whose block has no
     allocated page, scatter to the TRASH page instead.
+
+    Returns (out, writes): per row the ring token and its scales at t mod c,
+    and one arena page (payload and scales) at the row's destination page.
     """
     from repro.parallel.plan import as_plan
     plan = as_plan(plan)
-    rk_q, rv_q = layer_cache["raw_k_q"], layer_cache["raw_v_q"]
-    rk_s, rv_s = layer_cache["raw_k_s"], layer_cache["raw_v_s"]
     pk, pv = layer_cache["page_k"], layer_cache["page_v"]
     pk_s, pv_s = layer_cache["page_k_s"], layer_cache["page_v_s"]
     pt = layer_cache["page_table"]
-    B, c, Hkv, Dh = rk_q.shape
+    B, c, Hkv, Dh = layer_cache["raw_k_q"].shape
     Np, r = pk.shape[0], pk.shape[1]
     maxp = pt.shape[1]
     M = maxp * r
@@ -397,10 +458,11 @@ def paged_decode_attention(
 
     k_q, k_s = quantize_blockwise(k_t, (3,), dtype=pk.dtype, qmax=qmax)
     v_q, v_s = quantize_blockwise(v_t, (3,), dtype=pk.dtype, qmax=qmax)
-    rk_q = _row_update(rk_q, k_q, pos)
-    rv_q = _row_update(rv_q, v_q, pos)
-    rk_s = _row_update(rk_s, k_s, pos)
-    rv_s = _row_update(rv_s, v_s, pos)
+    writes = {"raw_k_q": row_write(pos, k_q), "raw_v_q": row_write(pos, v_q),
+              "raw_k_s": row_write(pos, k_s), "raw_v_s": row_write(pos, v_s)}
+    rk_q, rv_q, rk_s, rv_s = (
+        write_slots(layer_cache[n], writes[n])
+        for n in ("raw_k_q", "raw_v_q", "raw_k_s", "raw_v_s"))
 
     gk, gk_s = paged_gather(pk, pk_s, pt)
     gv, gv_s = paged_gather(pv, pv_s, pt)
@@ -430,16 +492,8 @@ def paged_decode_attention(
         pt, jnp.clip(blk, 0, maxp - 1)[:, None], axis=1)[:, 0]
     commit = done & (pt_blk >= 0) & (blk < maxp)
     dst = jnp.where(commit, pt_blk, trash)                  # (B,)
-    pk = pk.at[dst].set(fk_q)
-    pv = pv.at[dst].set(fv_q)
-    pk_s = pk_s.at[dst].set(fk_s)
-    pv_s = pv_s.at[dst].set(fv_s)
-
-    return out, {"raw_k_q": rk_q, "raw_v_q": rv_q,
-                 "raw_k_s": rk_s, "raw_v_s": rv_s,
-                 "page_k": pk, "page_v": pv,
-                 "page_k_s": pk_s, "page_v_s": pv_s,
-                 "page_table": pt}
+    writes.update(_page_writes(dst, fk_q, fv_q, fk_s, fv_s))
+    return out, writes
 
 
 def paged_prefill_chunk(
@@ -453,7 +507,7 @@ def paged_prefill_chunk(
     *,
     scale: Optional[float] = None,
     plan=None,                # AttentionPlan | backend string | None
-) -> Tuple[jax.Array, Dict[str, jax.Array]]:
+) -> Tuple[jax.Array, Dict[str, SlotWrite]]:
     """One chunked-prefill step over the paged, quantized cache.
 
     The chunk's P/c block folds are quantized per (row, block, head) and
@@ -463,21 +517,19 @@ def paged_prefill_chunk(
     blocks are visible CACHE-ROUNDED — the same chunked-admission rounding
     contract as the low-precision dense cache (see
     :func:`compressed_prefill_chunk`), one notch coarser. The raw ring is
-    untouched, as in the dense path.
+    untouched, as in the dense path. Returns (out, writes): the chunk's
+    pages, payload and scales.
     """
     from repro.parallel.plan import as_plan
     plan = as_plan(plan)
-    rk_q, rv_q = layer_cache["raw_k_q"], layer_cache["raw_v_q"]
-    rk_s, rv_s = layer_cache["raw_k_s"], layer_cache["raw_v_s"]
-    pk, pv = layer_cache["page_k"], layer_cache["page_v"]
-    pk_s, pv_s = layer_cache["page_k_s"], layer_cache["page_v_s"]
+    pdt = layer_cache["page_k"].dtype
     pt = layer_cache["page_table"]
     B, P, Hkv, Dh = k.shape
-    c = rk_q.shape[1]
+    c = layer_cache["raw_k_q"].shape[1]
     r = E.shape[-1]
-    Np = pk.shape[0]
+    Np = layer_cache["page_k"].shape[0]
     maxp = pt.shape[1]
-    qmax = _qmax_for(pk.dtype)
+    qmax = _qmax_for(pdt)
     trash = Np - 1
     scale_ = scale if scale is not None else q.shape[-1] ** -0.5
     if P % c != 0:
@@ -489,29 +541,26 @@ def paged_prefill_chunk(
     vf = v.astype(jnp.float32).reshape(B, nb, c, Hkv, Dh)
     kbar = compress_blocks(kf, E.astype(jnp.float32))       # (B, nb, r, Hkv, Dh)
     vbar = compress_blocks(vf, F.astype(jnp.float32))
-    bk_q, bk_s = quantize_blockwise(kbar, (2, 4), dtype=pk.dtype, qmax=qmax)
-    bv_q, bv_s = quantize_blockwise(vbar, (2, 4), dtype=pk.dtype, qmax=qmax)
+    bk_q, bk_s = quantize_blockwise(kbar, (2, 4), dtype=pdt, qmax=qmax)
+    bv_q, bv_s = quantize_blockwise(vbar, (2, 4), dtype=pdt, qmax=qmax)
 
     t0 = rowwise_t(t0, B)
     blk0 = t0 // c
     abs_blk = blk0[:, None] + jnp.arange(nb)[None, :]       # (B, nb)
     pids = jnp.take_along_axis(pt, jnp.clip(abs_blk, 0, maxp - 1), axis=1)
     dst = jnp.where((pids >= 0) & (abs_blk < maxp), pids, trash).reshape(-1)
-    pk = pk.at[dst].set(bk_q.reshape(B * nb, r, Hkv, Dh))
-    pv = pv.at[dst].set(bv_q.reshape(B * nb, r, Hkv, Dh))
-    pk_s = pk_s.at[dst].set(bk_s.reshape(B * nb, Hkv))
-    pv_s = pv_s.at[dst].set(bv_s.reshape(B * nb, Hkv))
+    writes = _page_writes(dst, bk_q.reshape(B * nb, r, Hkv, Dh),
+                          bv_q.reshape(B * nb, r, Hkv, Dh),
+                          bk_s.reshape(B * nb, Hkv), bv_s.reshape(B * nb, Hkv))
+    pk, pv, pk_s, pv_s = (write_slots(layer_cache[n], writes[n])
+                          for n in ("page_k", "page_v", "page_k_s", "page_v_s"))
 
     gk, gk_s = paged_gather(pk, pk_s, pt)
     gv, gv_s = paged_gather(pv, pv_s, pt)
     out = plan.chunk_prefill_attention_q(
         q, k, v, gk, gv, gk_s, gv_s, blk0,
         block_size=c, block_slots=r, scale=scale_)
-    return out, {"raw_k_q": rk_q, "raw_v_q": rv_q,
-                 "raw_k_s": rk_s, "raw_v_s": rv_s,
-                 "page_k": pk, "page_v": pv,
-                 "page_k_s": pk_s, "page_v_s": pv_s,
-                 "page_table": pt}
+    return out, writes
 
 
 # ---------------------------------------------------------------------------
@@ -544,24 +593,25 @@ def full_decode_attention(
     t: jax.Array,             # () or (B,) int32 per-row positions
     *,
     scale: Optional[float] = None,
-) -> Tuple[jax.Array, Dict[str, jax.Array]]:
+) -> Tuple[jax.Array, Dict[str, SlotWrite]]:
     """One decode step of standard causal attention with a full KV cache.
-    Writes and masks are per row; a scalar t broadcasts to all rows."""
+    Writes and masks are per row; a scalar t broadcasts to all rows.
+    Returns (out, writes): each row's token at t[b]."""
     ck, cv = layer_cache["k"], layer_cache["v"]
     B, S, Hkv, Dh = ck.shape
     H = q_t.shape[2]
     G = H // Hkv
     scale_ = scale if scale is not None else Dh ** -0.5
     t = rowwise_t(t, B)
-    ck = _row_update(ck, k_t.astype(ck.dtype), t)
-    cv = _row_update(cv, v_t.astype(cv.dtype), t)
+    writes = {"k": row_write(t, k_t), "v": row_write(t, v_t)}
+    ck, cv = write_slots(ck, writes["k"]), write_slots(cv, writes["v"])
     qg = q_t.reshape(B, Hkv, G, Dh)
     s = jnp.einsum("bhgd,bshd->bhgs", qg, ck).astype(jnp.float32) * scale_
     ok = jnp.arange(S)[None, :] <= t[:, None]               # (B, S)
     s = jnp.where(ok[:, None, None, :], s, NEG_INF)
     p = jax.nn.softmax(s, axis=-1).astype(q_t.dtype)
     out = jnp.einsum("bhgs,bshd->bhgd", p, cv).reshape(B, 1, H, Dh)
-    return out, {"k": ck, "v": cv}
+    return out, writes
 
 
 def full_prefill_chunk(
@@ -572,12 +622,13 @@ def full_prefill_chunk(
     t0: jax.Array,            # (B,) int32 — row's current length
     *,
     scale: Optional[float] = None,
-) -> Tuple[jax.Array, Dict[str, jax.Array]]:
+) -> Tuple[jax.Array, Dict[str, SlotWrite]]:
     """One chunked-prefill step of standard causal attention with a full KV
     cache: row b's chunk is written at positions [t0[b], t0[b] + P) and each
     query i attends cache positions ≤ t0[b] + i. Padded tail tokens
     (n_valid < P) write garbage the decode path overwrites position-by-
-    position before its mask can reach them."""
+    position before its mask can reach them. Returns (out, writes): each
+    row's P tokens at t0[b]."""
     ck, cv = layer_cache["k"], layer_cache["v"]
     B, S, Hkv, Dh = ck.shape
     P = q.shape[1]
@@ -585,8 +636,8 @@ def full_prefill_chunk(
     G = H // Hkv
     scale_ = scale if scale is not None else Dh ** -0.5
     t0 = rowwise_t(t0, B)
-    ck = _row_update(ck, k.astype(ck.dtype), t0)
-    cv = _row_update(cv, v.astype(cv.dtype), t0)
+    writes = {"k": row_write(t0, k), "v": row_write(t0, v)}
+    ck, cv = write_slots(ck, writes["k"]), write_slots(cv, writes["v"])
     qg = q.reshape(B, P, Hkv, G, Dh)
     s = jnp.einsum("bphgd,bshd->bhgps", qg, ck).astype(jnp.float32) * scale_
     qpos = t0[:, None] + jnp.arange(P)[None, :]              # (B, P)
@@ -594,4 +645,4 @@ def full_prefill_chunk(
     s = jnp.where(ok[:, None, None], s, NEG_INF)
     p = jax.nn.softmax(s, axis=-1).astype(q.dtype)
     out = jnp.einsum("bhgps,bshd->bphgd", p, cv).reshape(B, P, H, Dh)
-    return out, {"k": ck, "v": cv}
+    return out, writes

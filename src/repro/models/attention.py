@@ -195,9 +195,11 @@ def apply_attention_decode(
     *,
     shared_lin: Optional[Dict] = None,
     plan: Optional[plan_lib.AttentionPlan] = None,
-) -> Tuple[jax.Array, Dict[str, jax.Array]]:
-    """One-token decode step against the layer's cache. A (B,) t gives each
-    row its own position (rope + cache write + mask all per row)."""
+) -> Tuple[jax.Array, Dict[str, cache_lib.SlotWrite]]:
+    """One-token decode step against the layer's cache view. A (B,) t gives
+    each row its own position (rope + cache write + mask all per row).
+    Returns (out (B, 1, D), writes): the slots this step changes, as
+    core/cache.py's :class:`SlotWrite` per leaf."""
     if plan is None:
         plan = plan_lib.resolve_attention_plan(cfg)
     positions = t[None] if t.ndim == 0 else t[:, None]      # (1,) or (B, 1)
@@ -209,16 +211,16 @@ def apply_attention_decode(
         decode_fn = (cache_lib.paged_decode_attention
                      if "page_table" in layer_cache
                      else cache_lib.compressed_decode_attention)
-        out, new_cache = decode_fn(q, k, v, layer_cache, E, F, t, plan=plan)
+        out, writes = decode_fn(q, k, v, layer_cache, E, F, t, plan=plan)
     elif cfg.kind == "standard":
-        out, new_cache = cache_lib.full_decode_attention(
+        out, writes = cache_lib.full_decode_attention(
             q, k, v, layer_cache, t)
     else:
         raise ValueError(
             f"attention kind {cfg.kind!r} has no decode path "
             "(exact linformer is bidirectional/encoder-only)")
     B = x_t.shape[0]
-    return out.reshape(B, 1, -1) @ params["wo"], new_cache
+    return out.reshape(B, 1, -1) @ params["wo"], writes
 
 
 def apply_attention_prefill_chunk(
@@ -231,12 +233,13 @@ def apply_attention_prefill_chunk(
     shared_lin: Optional[Dict] = None,
     positions: Optional[jax.Array] = None,   # (B, P) absolute positions
     plan: Optional[plan_lib.AttentionPlan] = None,
-) -> Tuple[jax.Array, Dict[str, jax.Array]]:
+) -> Tuple[jax.Array, Dict[str, cache_lib.SlotWrite]]:
     """Chunked-prefill attention at a per-row offset, against the layer's
     slot-resident cache: row b's chunk covers absolute positions
     [t0[b], t0[b] + P). For linformer_causal t0 and P must be multiples of
     the block size (chunk boundaries are block-fold boundaries); standard
-    attention takes any offset. Returns (out (B, P, D'), updated cache)."""
+    attention takes any offset. Returns (out (B, P, D'), writes), as
+    :func:`apply_attention_decode`."""
     if plan is None:
         plan = plan_lib.resolve_attention_plan(cfg)
     if positions is None:
@@ -247,16 +250,16 @@ def apply_attention_prefill_chunk(
         prefill_fn = (cache_lib.paged_prefill_chunk
                       if "page_table" in layer_cache
                       else cache_lib.compressed_prefill_chunk)
-        out, new_cache = prefill_fn(q, k, v, layer_cache, E, F, t0, plan=plan)
+        out, writes = prefill_fn(q, k, v, layer_cache, E, F, t0, plan=plan)
     elif cfg.kind == "standard":
-        out, new_cache = cache_lib.full_prefill_chunk(
+        out, writes = cache_lib.full_prefill_chunk(
             q, k, v, layer_cache, t0)
     else:
         raise ValueError(
             f"attention kind {cfg.kind!r} has no chunked-prefill path "
             "(exact linformer is bidirectional/encoder-only)")
     B, P = x.shape[:2]
-    return out.reshape(B, P, -1) @ params["wo"], new_cache
+    return out.reshape(B, P, -1) @ params["wo"], writes
 
 
 def prefill_cache_entries(

@@ -15,6 +15,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.configs.base import ModelConfig
+from repro.core import cache as cache_lib
 from repro.core import linformer as lin_lib
 from repro.core.causal import chunked_attention_min_seq
 from repro.core.projections import effective_k
@@ -127,7 +128,9 @@ def apply_block_decode(
     shared_lin: Optional[Dict],
     ctx: Optional[ParallelCtx],
 ) -> Tuple[jax.Array, Dict, jax.Array]:
-    h, new_cache = attn_lib.apply_attention_decode(
+    """One block's decode step against the layer's cache view. Returns (x,
+    writes, moe_aux): `writes` names the cache slots the step changes."""
+    h, writes = attn_lib.apply_attention_decode(
         params["attn"], L.rms_norm(params["ln1"], x_t), layer_cache, t,
         cfg.attention, shared_lin=shared_lin,
         plan=plan_lib.resolve_attention_plan(cfg.attention, ctx))
@@ -137,7 +140,7 @@ def apply_block_decode(
         h, aux = moe_lib.apply_moe(params["moe"], hin, cfg.moe, cfg.mlp, ctx)
     else:
         h, aux = L.apply_mlp(params["mlp"], hin, cfg.mlp), jnp.zeros((), jnp.float32)
-    return x_t + h, new_cache, aux
+    return x_t + h, writes, aux
 
 
 def apply_block_prefill_chunk(
@@ -153,8 +156,8 @@ def apply_block_prefill_chunk(
 ) -> Tuple[jax.Array, Dict]:
     """One transformer block over a prefill chunk at a per-row offset
     (decode-path twin of `apply_block`, cache-writing like
-    `apply_block_decode` but P tokens at once)."""
-    h, new_cache = attn_lib.apply_attention_prefill_chunk(
+    `apply_block_decode` but P tokens at once). Returns (x, writes)."""
+    h, writes = attn_lib.apply_attention_prefill_chunk(
         params["attn"], L.rms_norm(params["ln1"], x), layer_cache, t0,
         cfg.attention, shared_lin=shared_lin, positions=positions,
         plan=plan_lib.resolve_attention_plan(cfg.attention, ctx))
@@ -164,7 +167,38 @@ def apply_block_prefill_chunk(
         h, _ = moe_lib.apply_moe(params["moe"], hin, cfg.moe, cfg.mlp, ctx)
     else:
         h = L.apply_mlp(params["mlp"], hin, cfg.mlp)
-    return x + h, new_cache
+    return x + h, writes
+
+
+def scan_cache_layers(step, x: jax.Array, params: Dict, cfg: ModelConfig,
+                      cache: Dict) -> Tuple[jax.Array, Dict]:
+    """Run ``step(layer_params, h, layer_view) -> (h, writes)`` over the
+    layers, writing each layer's cache in place.
+
+    The stacked cache (every leaf but ``lengths``, layer axis leading) rides
+    in the layer scan's carry, not as its xs/ys: layer i reads its view from
+    the carry and writes back only the slots ``writes`` names
+    (core/cache.py ``write_cache`` at layer i). So a step moves no whole
+    layer buffer, and a donated pool is updated in place. Returns (h, the
+    stack)."""
+    stack = {k: v for k, v in cache.items() if k != "lengths"}
+
+    def body(carry, inp):
+        h, st = carry
+        lp, i = inp
+        view = {k: jax.lax.dynamic_index_in_dim(v, i, 0, keepdims=False)
+                for k, v in st.items()}
+        h, writes = step(lp, h, view)
+        return (h, cache_lib.write_cache(st, writes, i)), None
+
+    if cfg.scan_layers:
+        (x, stack), _ = jax.lax.scan(
+            body, (x, stack),
+            (params["layers"], jnp.arange(cfg.num_layers, dtype=jnp.int32)))
+    else:
+        for i, lp in enumerate(params["layers_list"]):
+            (x, stack), _ = body((x, stack), (lp, i))
+    return x, stack
 
 
 def prefill_chunk(
@@ -205,24 +239,11 @@ def prefill_chunk(
     x = shard_activation(x, ctx)
     shared_lin = params.get("shared", {}).get("lin")
 
-    layer_caches = {k: v for k, v in cache.items() if k != "lengths"}
-
-    def body(h, inp):
-        lp, lc = inp
-        h2, new_lc = apply_block_prefill_chunk(
+    x, new_caches = scan_cache_layers(
+        lambda lp, h, lc: apply_block_prefill_chunk(
             lp, h, lc, t0, cfg, positions=positions, shared_lin=shared_lin,
-            ctx=ctx)
-        return h2, new_lc
-
-    if cfg.scan_layers:
-        x, new_caches = jax.lax.scan(body, x, (params["layers"], layer_caches))
-    else:
-        outs = []
-        for i, lp in enumerate(params["layers_list"]):
-            lc = jax.tree.map(lambda a: a[i], layer_caches)
-            x, nc = body(x, (lp, lc))
-            outs.append(nc)
-        new_caches = jax.tree.map(lambda *xs: jnp.stack(xs), *outs)
+            ctx=ctx),
+        x, params, cfg, cache)
 
     # logits only at each row's last REAL token (padded rows' tail is junk)
     h_last = jnp.take_along_axis(
@@ -450,7 +471,9 @@ def decode_step(
 ) -> Tuple[jax.Array, Dict]:
     """One decode step. batch_t: {"tokens": (B,1)} or {"embeds": (B,1,D)}.
     Returns (logits (B,1,V), updated cache). Positions are per row: row b
-    decodes at cache["lengths"][b]."""
+    decodes at cache["lengths"][b]. The cache is written in place
+    (`scan_cache_layers`): per layer, each row's token and, for the
+    compressed caches, the r slots of its current block."""
     t = cache["lengths"]                    # (B,) per-row positions
     if cfg.embedding_inputs:
         x = batch_t["embeds"].astype(_dtype(cfg))
@@ -461,23 +484,10 @@ def decode_step(
     x = shard_activation(x, ctx)
     shared_lin = params.get("shared", {}).get("lin")
 
-    layer_caches = {k: v for k, v in cache.items() if k != "lengths"}
-
-    def body(h, inp):
-        lp, lc = inp
-        h2, new_lc, _ = apply_block_decode(lp, h, lc, t, cfg,
-                                           shared_lin=shared_lin, ctx=ctx)
-        return h2, new_lc
-
-    if cfg.scan_layers:
-        x, new_caches = jax.lax.scan(body, x, (params["layers"], layer_caches))
-    else:
-        outs = []
-        for i, lp in enumerate(params["layers_list"]):
-            lc = jax.tree.map(lambda a: a[i], layer_caches)
-            x, nc = body(x, (lp, lc))
-            outs.append(nc)
-        new_caches = jax.tree.map(lambda *xs: jnp.stack(xs), *outs)
+    x, new_caches = scan_cache_layers(
+        lambda lp, h, lc: apply_block_decode(
+            lp, h, lc, t, cfg, shared_lin=shared_lin, ctx=ctx)[:2],
+        x, params, cfg, cache)
 
     logits = logits_from_hidden(params, cfg, x, ctx)
     new_caches["lengths"] = t + 1

@@ -12,6 +12,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.configs.base import ModelConfig
+from repro.core import cache as cache_lib
 from repro.core import linformer as lin_lib
 from repro.models import attention as attn_lib
 from repro.models import layers as L
@@ -170,7 +171,8 @@ def decode_step(
     shared_lin = params.get("shared", {}).get("lin")
     every = cfg.hybrid_attn_every
     n_inv = n_attn_invocations(cfg)
-    new_ssm, new_conv, new_attn = [], [], []
+    new_ssm, new_conv = [], []
+    attn = cache["attn"]
 
     def trunk_step(x, i):
         lp = jax.tree.map(lambda a: a[i], params["trunk"])
@@ -185,11 +187,11 @@ def decode_step(
     for g in range(n_inv):
         for i in range(g * every, (g + 1) * every):
             x = trunk_step(x, i)
-        lc = jax.tree.map(lambda a: a[g], cache["attn"])
-        h, nlc = attn_lib.apply_attention_decode(
+        lc = jax.tree.map(lambda a: a[g], attn)
+        h, writes = attn_lib.apply_attention_decode(
             sb["attn"], L.rms_norm(sb["ln1"], x), lc, t, cfg.attention,
             shared_lin=shared_lin)
-        new_attn.append(nlc)
+        attn = cache_lib.write_cache(attn, writes, g)
         x = x + h
         x = x + L.apply_mlp(sb["mlp"], L.rms_norm(sb["ln2"], x), cfg.mlp)
     for i in range(n_inv * every, cfg.num_layers):
@@ -200,6 +202,6 @@ def decode_step(
     return logits, {
         "mamba_ssm": jnp.stack(new_ssm),
         "mamba_conv": jnp.stack(new_conv),
-        "attn": jax.tree.map(lambda *xs: jnp.stack(xs), *new_attn),
+        "attn": attn,
         "length": t + 1,
     }

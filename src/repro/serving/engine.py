@@ -371,17 +371,22 @@ class ServingEngine:
 
     def _pool_prefill_remainder_impl(self, params, pool: Dict,
                                      tokens: jax.Array, idx: jax.Array):
-        """Feed the sub-block remainder of a prompt (rem = tokens.shape[1]
-        < block size) through the decode path against the gathered rows —
-        exactly what the monolithic prefill does for its remainder, but
-        batched over every request in the same remainder group."""
-        sub = self._gather_rows(pool, idx)
-        logits = None
-        for t in range(tokens.shape[1]):
-            lg, sub = model_lib.decode_step(
-                params, self.cfg, {"tokens": tokens[:, t:t + 1]}, sub,
+        """Feed the sub-block remainder of a prompt (1 <= rem =
+        tokens.shape[1] < block size) through the decode path against the
+        gathered rows — exactly what the monolithic prefill does for its
+        remainder, but batched over every request in the same remainder
+        group. The steps run as one `lax.scan`, so a remainder length
+        compiles one decode step, not rem."""
+        def step(carry, tok):
+            logits, cache = model_lib.decode_step(
+                params, self.cfg, {"tokens": tok[:, None]}, carry[1],
                 ctx=self.ctx)
-            logits = lg[:, 0]
+            return (logits[:, 0], cache), None
+
+        sub = self._gather_rows(pool, idx)
+        first = jax.eval_shape(step, (None, sub), tokens[:, 0])[0][0]
+        (logits, sub), _ = jax.lax.scan(
+            step, (jnp.zeros(first.shape, first.dtype), sub), tokens.T)
         return self._scatter_rows(pool, sub, idx), logits
 
     @staticmethod

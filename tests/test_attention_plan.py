@@ -186,10 +186,19 @@ def test_multi_device_chunk_prefill_and_decode_parity():
         }
         t0 = jnp.asarray([0, 8, 16, 24], jnp.int32)   # per-row offsets
 
-        o_ref, c_ref = cache_lib.compressed_prefill_chunk(
-            q, k, v, lc, E, F, t0, plan="reference")
-        o_one, c_one = cache_lib.compressed_prefill_chunk(
-            q, k, v, lc, E, F, t0, plan="fused")
+        def stepped(step, plan):
+            # the step, with the slots it writes applied to its cache
+            def run(*a):
+                out, writes = step(*a, plan=plan)
+                return out, cache_lib.write_cache(a[3], writes)
+            return run
+
+        prefill = lambda plan: stepped(cache_lib.compressed_prefill_chunk,
+                                       plan)
+        decode = lambda plan: stepped(cache_lib.compressed_decode_attention,
+                                      plan)
+        o_ref, c_ref = prefill("reference")(q, k, v, lc, E, F, t0)
+        o_one, c_one = prefill("fused")(q, k, v, lc, E, F, t0)
         np.testing.assert_allclose(o_one, o_ref, atol=1e-4, rtol=1e-4)
 
         # decode single-device baselines
@@ -197,10 +206,8 @@ def test_multi_device_chunk_prefill_and_decode_parity():
         kd = k[:, :1]
         vd = v[:, :1]
         td = jnp.asarray([3, 7, 12, 20], jnp.int32)
-        do_ref, dc_ref = cache_lib.compressed_decode_attention(
-            qd, kd, vd, lc, E, F, td, plan="reference")
-        do_one, dc_one = cache_lib.compressed_decode_attention(
-            qd, kd, vd, lc, E, F, td, plan="fused")
+        do_ref, dc_ref = decode("reference")(qd, kd, vd, lc, E, F, td)
+        do_one, dc_one = decode("fused")(qd, kd, vd, lc, E, F, td)
         np.testing.assert_allclose(do_one, do_ref, atol=1e-4, rtol=1e-4)
 
         for name, (ms, ss) in MESHES.items():
@@ -209,12 +216,8 @@ def test_multi_device_chunk_prefill_and_decode_parity():
             plan = resolve_attention_plan(
                 cfg_(Hkv).attention, ctx)
             with mesh:
-                o_m, c_m = jax.jit(
-                    lambda *a: cache_lib.compressed_prefill_chunk(
-                        *a, plan=plan))(q, k, v, lc, E, F, t0)
-                do_m, dc_m = jax.jit(
-                    lambda *a: cache_lib.compressed_decode_attention(
-                        *a, plan=plan))(qd, kd, vd, lc, E, F, td)
+                o_m, c_m = jax.jit(prefill(plan))(q, k, v, lc, E, F, t0)
+                do_m, dc_m = jax.jit(decode(plan))(qd, kd, vd, lc, E, F, td)
             np.testing.assert_allclose(np.asarray(o_m), np.asarray(o_one),
                                        atol=1e-5, rtol=1e-5)
             np.testing.assert_allclose(np.asarray(do_m), np.asarray(do_one),

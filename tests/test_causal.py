@@ -7,6 +7,7 @@ import pytest
 from repro.core import (blockwise_causal_attention,
                         blockwise_causal_attention_chunked,
                         compressed_decode_attention, init_compressed_cache)
+from tests.conftest import applied_step
 
 
 def _qkv(B=2, S=32, H=4, Hkv=2, Dh=8, seed=0):
@@ -71,7 +72,8 @@ class TestDecode:
         lc = {kk: vv[0] for kk, vv in cache.items() if kk != "lengths"}
         outs = []
         for t in range(32):
-            o, lc = compressed_decode_attention(
+            o, lc = applied_step(
+                compressed_decode_attention,
                 q[:, t:t + 1], k[:, t:t + 1], v[:, t:t + 1], lc, EF, EF,
                 jnp.int32(t))
             outs.append(o)
@@ -93,11 +95,13 @@ class TestDecode:
             num_kv_heads=2, head_dim=8, dtype=jnp.float32)
         lc = {kk: vv[0] for kk, vv in cache.items() if kk != "lengths"}
         for t in range(7):
-            _, lc = compressed_decode_attention(
+            _, lc = applied_step(
+                compressed_decode_attention,
                 q[:, t:t + 1], k[:, t:t + 1], v[:, t:t + 1], lc, EF, EF,
                 jnp.int32(t))
         assert float(jnp.abs(lc["comp_k"]).sum()) == 0.0   # not folded yet
-        _, lc = compressed_decode_attention(
+        _, lc = applied_step(
+            compressed_decode_attention,
             q[:, 7:8], k[:, 7:8], v[:, 7:8], lc, EF, EF, jnp.int32(7))
         assert float(jnp.abs(lc["comp_k"][:, :4]).sum()) > 0.0  # folded
         assert float(jnp.abs(lc["comp_k"][:, 4:]).sum()) == 0.0

@@ -35,6 +35,7 @@ from repro.core.causal import blockwise_causal_prefix_attention
 from repro.models import model as M
 from repro.serving import ServingEngine, ShedResult
 from repro.serving.scheduler import SHED_PAGES_EXHAUSTED
+from tests.conftest import applied_step
 
 # Documented per-storage-dtype tolerance bands (max |paged - dense fp32|
 # attention output, pre-softmax inputs O(1) normal). int8 rounds to
@@ -106,9 +107,11 @@ def _stream(S, *, plan="reference", page_dtype="int8", t0=None, seed=0):
     for t in range(S):
         tt = base + t
         sl = (q[:, t:t + 1], k[:, t:t + 1], v[:, t:t + 1])
-        od, dlc = cache_lib.compressed_decode_attention(
+        od, dlc = applied_step(
+            cache_lib.compressed_decode_attention,
             *sl, dlc, E, F, tt, plan="reference")
-        op, plc = cache_lib.paged_decode_attention(
+        op, plc = applied_step(
+            cache_lib.paged_decode_attention,
             *sl, plc, E, F, tt, plan=plan)
         outs_d.append(od)
         outs_p.append(op)
@@ -224,9 +227,11 @@ class TestDecodeParity:
         poisoned["page_v_s"] = clean["page_v_s"].at[trash].set(1e6)
         for t in range(24):
             sl = (q[:, t:t + 1], k[:, t:t + 1], v[:, t:t + 1])
-            oc, clean = cache_lib.paged_decode_attention(
+            oc, clean = applied_step(
+                cache_lib.paged_decode_attention,
                 *sl, clean, E, F, jnp.full((B,), t, jnp.int32))
-            op, poisoned = cache_lib.paged_decode_attention(
+            op, poisoned = applied_step(
+                cache_lib.paged_decode_attention,
                 *sl, poisoned, E, F, jnp.full((B,), t, jnp.int32))
             np.testing.assert_array_equal(np.asarray(oc), np.asarray(op))
 
@@ -237,7 +242,8 @@ class TestDecodeParity:
         q, k, v, E, F = _inputs(8, seed=5)
         plc = _paged_layer_cache(table="empty")
         for t in range(8):
-            _, plc = cache_lib.paged_decode_attention(
+            _, plc = applied_step(
+                cache_lib.paged_decode_attention,
                 q[:, t:t + 1], k[:, t:t + 1], v[:, t:t + 1],
                 plc, E, F, jnp.full((B,), t, jnp.int32))
         pages_k = np.asarray(plc["page_k"])
@@ -258,9 +264,11 @@ class TestPrefillParity:
         for t0 in range(0, S, P):
             tt = jnp.full((B,), t0, jnp.int32)
             sl = (q[:, t0:t0 + P], k[:, t0:t0 + P], v[:, t0:t0 + P])
-            od, dlc = cache_lib.compressed_prefill_chunk(
+            od, dlc = applied_step(
+                cache_lib.compressed_prefill_chunk,
                 *sl, dlc, E, F, tt, plan="reference")
-            op, plc = cache_lib.paged_prefill_chunk(
+            op, plc = applied_step(
+                cache_lib.paged_prefill_chunk,
                 *sl, plc, E, F, tt, plan=plan)
             outs_d.append(od)
             outs_p.append(op)
@@ -291,10 +299,12 @@ class TestPrefillParity:
         tests/test_chunked_prefill.py::TestPrefixAttentionParity)."""
         q, k, v, E, F = _inputs(32, seed=6)
         plc = _paged_layer_cache()
-        _, plc = cache_lib.paged_prefill_chunk(
+        _, plc = applied_step(
+            cache_lib.paged_prefill_chunk,
             q[:, :16], k[:, :16], v[:, :16], plc, E, F,
             jnp.zeros((B,), jnp.int32))
-        out, plc = cache_lib.paged_prefill_chunk(
+        out, plc = applied_step(
+            cache_lib.paged_prefill_chunk,
             q[:, 16:], k[:, 16:], v[:, 16:], plc, E, F,
             jnp.full((B,), 16, jnp.int32))
         gk, gk_s = cache_lib.paged_gather(

@@ -13,6 +13,7 @@ from repro.core.cache import (compressed_decode_attention,
 from repro.models import model as M
 from repro.serving import (Request, Scheduler, ServingEngine, ShedResult,
                            SlotPool)
+from tests.conftest import applied_step
 
 
 def _tiny_cfg(max_seq=64):
@@ -115,7 +116,8 @@ class TestPerRowLengthsParity:
         q, k, v = kvs
         lc = _layer_cache(1)
         for t in range(t_stop):
-            _, lc = compressed_decode_attention(
+            _, lc = applied_step(
+                compressed_decode_attention,
                 q[:, t:t + 1], k[:, t:t + 1], v[:, t:t + 1], lc,
                 self.EF, self.EF, jnp.int32(t), plan=backend)
         return lc
@@ -138,7 +140,8 @@ class TestPerRowLengthsParity:
         for b, t in enumerate(positions):
             kvs = (q[b:b + 1], k[b:b + 1], v[b:b + 1])
             lc = self._roll_to(t, kvs, backend)
-            o, lc = compressed_decode_attention(
+            o, lc = applied_step(
+                compressed_decode_attention,
                 q[b:b + 1, t:t + 1], k[b:b + 1, t:t + 1],
                 v[b:b + 1, t:t + 1], lc, self.EF, self.EF, jnp.int32(t),
                 plan=backend)
@@ -154,7 +157,8 @@ class TestPerRowLengthsParity:
         qs = jnp.stack([q[b, t] for b, t in enumerate(positions)])[:, None]
         kss = jnp.stack([k[b, t] for b, t in enumerate(positions)])[:, None]
         vs = jnp.stack([v[b, t] for b, t in enumerate(positions)])[:, None]
-        out_b, lc_b = compressed_decode_attention(
+        out_b, lc_b = applied_step(
+            compressed_decode_attention,
             qs, kss, vs, lc_b, self.EF, self.EF,
             jnp.asarray(positions, jnp.int32), plan=backend)
 
@@ -174,9 +178,11 @@ class TestPerRowLengthsParity:
         k = jax.random.normal(ks[1], (2, 1, 2, 8))
         v = jax.random.normal(ks[2], (2, 1, 2, 8))
         lc = _layer_cache(2)
-        o_s, c_s = compressed_decode_attention(
+        o_s, c_s = applied_step(
+            compressed_decode_attention,
             q, k, v, lc, self.EF, self.EF, jnp.int32(3), plan=backend)
-        o_v, c_v = compressed_decode_attention(
+        o_v, c_v = applied_step(
+            compressed_decode_attention,
             q, k, v, lc, self.EF, self.EF, jnp.full((2,), 3, jnp.int32),
             plan=backend)
         np.testing.assert_array_equal(o_s, o_v)
@@ -193,10 +199,12 @@ class TestPerRowLengthsParity:
         k = jax.random.normal(ks[3], (B, 1, Hkv, Dh))
         v = jax.random.normal(ks[4], (B, 1, Hkv, Dh))
         ts = jnp.asarray([4, 11], jnp.int32)
-        out_b, cb = full_decode_attention(
+        out_b, cb = applied_step(
+            full_decode_attention,
             q, k, v, {"k": cache_k, "v": cache_v}, ts)
         for b in range(B):
-            out_1, c1 = full_decode_attention(
+            out_1, c1 = applied_step(
+                full_decode_attention,
                 q[b:b + 1], k[b:b + 1], v[b:b + 1],
                 {"k": cache_k[b:b + 1], "v": cache_v[b:b + 1]},
                 jnp.int32(int(ts[b])))

@@ -214,3 +214,42 @@ def test_kernel_op_keeps_the_name_readers_match(one_chip, op):
                if 'custom_call_target="tpu_custom_call"' in line]
     assert kernels and all(re.fullmatch(rf"%{op}(\.\d+)?", k)
                            for k in kernels), kernels
+
+
+def test_decode_chunk_writes_pool_in_place(one_chip, monkeypatch):
+    """The serving decode chunk (`model.decode_scan`, fused kernels through
+    Mosaic) at qwen3-8b's widths over a 4-row bf16 pool of the serving
+    cell's depth (max_seq 32768, M = 2048 slots): inside its loops nothing
+    copies, selects over or writes a whole stacked cache leaf or layer slot
+    buffer. A copy in the entry computation is a layout change of the
+    donated pool, once per call, not per step."""
+    import dataclasses
+
+    from repro.models import model as M
+    from tests.conftest import hlo_whole_buffer_ops
+    monkeypatch.setattr(ops, "_auto_interpret",
+                        lambda interpret: bool(interpret))
+    a = get_config("qwen3-8b").attention
+    cfg = dataclasses.replace(
+        get_config("qwen3-8b"), num_layers=2, dtype="bfloat16",
+        attention=dataclasses.replace(a, backend="fused"))
+    B, max_seq, L = 4, 32768, cfg.num_layers
+    c, r = a.linformer.block_size, a.linformer.block_slots
+    layer = (B, max_seq // c * r, a.num_kv_heads, a.head_dim)
+    whole = {layer, (L,) + layer,
+             (L, B, c, a.num_kv_heads, a.head_dim)}
+    spec = lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=one_chip)
+    params = jax.tree.map(spec, jax.eval_shape(
+        lambda: M.init_params(jax.random.PRNGKey(0), cfg)))
+    cache = jax.tree.map(spec, jax.eval_shape(
+        lambda: M.init_cache(cfg, batch=B, max_seq=max_seq, dtype=BF16)))
+    rows = lambda dt: jax.ShapeDtypeStruct((B,), dt, sharding=one_chip)
+    key = jax.ShapeDtypeStruct((2,), jnp.uint32, sharding=one_chip)
+    fn = jax.jit(lambda p, cur, fin, ca, k: M.decode_scan(
+        p, cfg, cur, fin, ca, k, n_steps=4, eos_id=0), donate_argnums=(3,))
+    hlo = fn.lower(params, rows(I32), rows(jnp.bool_), cache,
+                   key).compile().as_text()
+    assert "fused_decode_attention" in hlo
+    assert [(op, shape) for entry, op, shape in
+            hlo_whole_buffer_ops(hlo, whole)
+            if not (entry and op == "copy")] == []
