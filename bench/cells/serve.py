@@ -3,7 +3,11 @@
 Set-up makes the weights from the seed, builds one `ServingEngine` on the
 dense pool and warms every program the mix uses (a prefill chunk, one
 remainder program per remainder length, the decode chunk) with one request
-per remainder length.
+per remainder length. A cell on several chips runs all of it, the window
+and the check too, under one mesh that shards the model over every chip
+(tensor parallelism, `model` = chips): each chip draws its own shard of the
+weights, laid out by the program's sharding rules, and the engine is given
+the mesh. On one chip there is no mesh.
 
 The window then drives `Scheduler.run` over a fresh `SlotPool`. Each
 request is submitted when it is due: the scheduler reports every phase
@@ -24,10 +28,12 @@ outside the vocabulary).
 In a traced run the benchmark counts the work of each scheduler span from
 the pool's slots, as the scheduler chooses its rows; the count is held
 against the rows and tokens that the span itself reports, and against the
-decode-chunk programs in the trace, and the run fails where they differ.
+decode-chunk programs that each chip ran in the trace, and the run fails
+where they differ.
 """
 from __future__ import annotations
 
+import contextlib
 import gc
 import json
 import os
@@ -250,11 +256,26 @@ def _agree(span: str, args: Dict, rows: int, tokens) -> None:
             "scheduler's choice of rows")
 
 
-def _engine(cell, params, mc):
+def _parallel(cell):
+    """The program's parallel context for a cell on several chips: one
+    mesh over them, all on the `model` axis (the program's own debug mesh,
+    as its chip smoke test builds it). None on one chip."""
+    if cell.chips == 1:
+        return None
+    from repro.launch.mesh import make_local_mesh
+    from repro.parallel.sharding import ParallelCtx
+    return ParallelCtx(mesh=make_local_mesh(model_shards=cell.chips))
+
+
+def _scope(ctx):
+    return contextlib.nullcontext() if ctx is None else ctx.mesh
+
+
+def _engine(cell, params, mc, ctx):
     import jax.numpy as jnp
     from repro.serving import ServingEngine
     mix = cell.mix
-    return ServingEngine(params, mc, max_seq=mix["max_seq"],
+    return ServingEngine(params, mc, max_seq=mix["max_seq"], ctx=ctx,
                          cache_dtype=jnp.dtype(mc.dtype),
                          decode_chunk=mix["decode_chunk"],
                          prefill_chunk=mix["prefill_chunk"])
@@ -272,6 +293,20 @@ def _warm(cell, engine) -> None:
         sched.submit(Request(rid=i, tokens=tuple(range(4, 4 + block + rem)),
                              max_new_tokens=2 * mix["decode_chunk"]))
     sched.run()
+    if engine.ctx is not None:
+        # on a mesh the logits come out sharded over the vocabulary and the
+        # sampling key replicated over the chips after a decode chunk, so
+        # each count of rows a prefill launch returns, and an admission
+        # after a decode chunk, is a program of its own: admit 1, 2, ...
+        # pool_rows requests at once, each wave after the last one's decode
+        rid = len(mix["prompt"]["remainders"])
+        for g in range(1, mix["pool_rows"] + 1):
+            for _ in range(g):
+                sched.submit(Request(rid=rid,
+                                     tokens=tuple(range(4, 5 + block)),
+                                     max_new_tokens=mix["decode_chunk"] + 1))
+                rid += 1
+            sched.run()
     del sched
     # the pool returns the first g rows of its padded logits: one small
     # slicing program per g, which a busy window reaches for every g
@@ -327,22 +362,48 @@ def judge(res: Dict, limits: Dict):
         and res["tokens"] >= limits["min_tokens"]
 
 
-def setup(cell, seed: int):
-    """Weights, engine and warm programs: everything before the window."""
+def _shapes_and_layout(mc, ctx):
+    """The parameter tree's shapes, and its shardings under `ctx` (None
+    on one chip)."""
     import jax
     from repro.models import model as M
-    mc = harness.model_config(cell.config)
+    from repro.parallel.sharding import param_shardings
     shapes = jax.eval_shape(lambda: M.init_params(jax.random.PRNGKey(0), mc))
-    params = weights.make(shapes, seed, cell.config["vocab_size"])
-    engine = _engine(cell, params, mc)
+    return shapes, None if ctx is None else param_shardings(shapes, ctx)
+
+
+def setup(cell, seed: int, ctx=None):
+    """Weights, engine and warm programs: everything before the window."""
+    mc = harness.model_config(cell.config)
+    shapes, layout = _shapes_and_layout(mc, ctx)
+    params = weights.make(shapes, seed, cell.config["vocab_size"], layout)
+    engine = _engine(cell, params, mc, ctx)
     _warm(cell, engine)
     return params, engine
 
 
+def _decode_runs_agree(red: Dict, chunks: int, chips: int) -> None:
+    """Every chip that ran a program in the traced window ran the decode
+    chunk's program once per decode chunk, and `chips` chips did."""
+    ran = {dev: n.get(DECODE_MODULE, 0)
+           for dev, n in red["module_n_by_device"].items()}
+    if len(ran) != chips or any(n != chunks for n in ran.values()):
+        raise WorkMismatch(
+            f"{DECODE_MODULE} ran {ran} times on each device in the traced "
+            f"window, over {chunks} decode chunks on {chips} chips")
+
+
 def run(cell, seed: int, seconds: float, trace: bool, device: Dict,
         t_start: float) -> Dict:
+    ctx = _parallel(cell)
+    with _scope(ctx):
+        return _run(cell, ctx, seed, seconds, trace, device, t_start)
+
+
+def _run(cell, ctx, seed: int, seconds: float, trace: bool, device: Dict,
+         t_start: float) -> Dict:
     compiles = harness.count_compiles()
-    params, engine = setup(cell, seed)
+    params, engine = setup(cell, seed, ctx)
     trace_dir = None
     if trace:
         trace_dir = os.path.join(harness.WORK_DIR, f"trace-{cell.name}")
@@ -381,11 +442,7 @@ def run(cell, seed: int, seconds: float, trace: bool, device: Dict,
     if trace:
         red = trace_reduce.reduce(trace_reduce.load(
             trace_reduce.find_xplane(trace_dir), SPANS + (WAIT,)))
-        ran = red["module_n"].get(DECODE_MODULE, 0)
-        if ran != win.work["decode_chunks"]:
-            raise WorkMismatch(
-                f"{DECODE_MODULE} ran {ran} times in the traced window, "
-                f"over {win.work['decode_chunks']} decode chunks")
+        _decode_runs_agree(red, win.work["decode_chunks"], cell.chips)
         record = {"trace": red, "work": win.work,
                   "launches": win.launches,
                   "peak": harness.peaks(device["kind"])}
@@ -421,11 +478,15 @@ def calibrate(cell, seeds, control_seeds, seconds, sweep, device) -> None:
     widest gap; on the control seeds, the widest gap of the token that the
     fp8 reference puts first at the same positions. Every line holds its
     numbers to the cell's limits under `checks` and says `correct`."""
-    import jax
-    from repro.models import model as M
-    params, engine = setup(cell, seeds[0])
-    mc = engine.cfg
-    shapes = jax.eval_shape(lambda: M.init_params(jax.random.PRNGKey(0), mc))
+    ctx = _parallel(cell)
+    with _scope(ctx):
+        _calibrate(cell, ctx, seeds, control_seeds, seconds, sweep, device)
+
+
+def _calibrate(cell, ctx, seeds, control_seeds, seconds, sweep,
+               device) -> None:
+    params, engine = setup(cell, seeds[0], ctx)
+    shapes, layout = _shapes_and_layout(engine.cfg, ctx)
     for rate in sweep:
         win = _Window(cell, engine, seeds[0], seconds, None, rate)
         win.run(device["kind"])
@@ -447,7 +508,8 @@ def calibrate(cell, seeds, control_seeds, seconds, sweep, device) -> None:
     for seed in seeds:
         engine.params = params = None
         gc.collect()
-        params = weights.make(shapes, seed, cell.config["vocab_size"])
+        params = weights.make(shapes, seed, cell.config["vocab_size"],
+                              layout)
         engine.params = params
         win = _Window(cell, engine, seed, seconds)
         win.run(device["kind"])

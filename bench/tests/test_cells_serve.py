@@ -74,8 +74,8 @@ def test_served_padding_ids_are_caught(monkeypatch):
                         replace(real_cfg(dict(cfg, vocab_size=512)),
                                 vocab_size=500))
 
-    def planted(shapes, seed, vocab):
-        p = real_make(shapes, seed, vocab)
+    def planted(shapes, seed, vocab, shardings=None):
+        p = real_make(shapes, seed, vocab, shardings)
         pad = p["lm_head"].shape[1] - vocab
         noise = 3.0 * jax.random.normal(jax.random.PRNGKey(seed),
                                         (p["lm_head"].shape[0], pad))

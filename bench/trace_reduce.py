@@ -20,7 +20,10 @@ What it computes, over the window:
 * busy seconds: the union of the operation intervals, averaged over the
   devices; the idle share is 1 − busy / window;
 * device seconds by operation name and by module name, and how many times
-  each module ran;
+  each module ran, over all devices and on each device;
+* the device seconds of collective operations (`COLLECTIVES`), summed over
+  devices, and the part of them during which no other operation runs on
+  that device (exposed: nothing overlaps it);
 * the longest idle gaps, each named by the host annotation that overlaps
   it most (what the host was doing while the device waited).
 
@@ -40,6 +43,14 @@ OPS_LINE = "XLA Ops"
 MODULES_LINE = "XLA Modules"
 
 CONTAINERS = ("while", "conditional", "call")
+
+# Operations that move data between chips: a name containing one of these
+# is a collective, its `-start`/`-done` halves and fusions included. The
+# tp=4 serving path on a TPU v5e host runs `all-reduce` and `all-gather`,
+# both synchronous: the "XLA Ops" line runs one operation at a time, so a
+# synchronous collective is exposed whole.
+COLLECTIVES = ("all-reduce", "all-gather", "reduce-scatter",
+               "collective-permute", "all-to-all")
 
 Interval = Tuple[float, float]          # seconds
 
@@ -150,21 +161,48 @@ def label_gap(gap: Interval, host) -> str:
     return name
 
 
+def is_collective(name: str) -> bool:
+    return any(c in name for c in COLLECTIVES)
+
+
+def overlap_s(a: Sequence[Interval], b: Sequence[Interval]) -> float:
+    """Seconds in both of two merged, sorted interval lists."""
+    i = j = 0
+    total = 0.0
+    while i < len(a) and j < len(b):
+        total += _overlap(a[i], b[j])
+        if a[i][1] < b[j][1]:
+            i += 1
+        else:
+            j += 1
+    return total
+
+
 def reduce(trace: Trace, top: int = 10) -> Dict:
     """busy_s (mean over devices), window_s, op_s / module_s by name
     (summed over devices), module_n (runs of each module, over devices),
-    and the `top` longest device operations and idle gaps of the first
-    device."""
+    module_n_by_device (runs of each module on each device that ran one),
+    collective_s and collective_exposed_s (summed over devices), and the
+    `top` longest device operations and idle gaps of the first device."""
     w = trace.window
     window_s = w[1] - w[0]
     busy, op_s, mod_s = [], defaultdict(float), defaultdict(float)
     mod_n = defaultdict(int)
+    by_dev: Dict[str, Dict[str, int]] = {}
+    coll_s = exposed_s = 0.0
     first_gaps: List[Interval] = []
     for i, dev in enumerate(sorted(trace.ops)):
         evs = list(clip(trace.ops[dev], w))
+        coll, other = [], []
         for name, s, e in evs:
-            if name not in CONTAINERS:
-                op_s[name] += e - s
+            if name in CONTAINERS:
+                continue
+            op_s[name] += e - s
+            (coll if is_collective(name) else other).append((s, e))
+        coll_s += sum(e - s for s, e in coll)
+        coll = union(coll)
+        exposed_s += sum(e - s for s, e in coll) - overlap_s(coll,
+                                                             union(other))
         merged = union([(s, e) for _, s, e in evs])
         busy.append(sum(e - s for s, e in merged))
         if i == 0:
@@ -173,6 +211,8 @@ def reduce(trace: Trace, top: int = 10) -> Dict:
         for name, s, e in clip(evs, w):
             mod_s[name] += e - s
             mod_n[name] += 1
+            n = by_dev.setdefault(dev, {})
+            n[name] = n.get(name, 0) + 1
     longest = sorted(first_gaps, key=lambda g: g[0] - g[1])[:top]
     return {
         "window_s": window_s,
@@ -180,6 +220,9 @@ def reduce(trace: Trace, top: int = 10) -> Dict:
         "op_s": dict(op_s),
         "module_s": dict(mod_s),
         "module_n": dict(mod_n),
+        "module_n_by_device": by_dev,
+        "collective_s": coll_s,
+        "collective_exposed_s": exposed_s,
         "device_ops": sorted(([n, t] for n, t in op_s.items()),
                              key=lambda kv: -kv[1])[:top],
         "idle_gaps": [[label_gap(g, trace.host), g[1] - g[0]]

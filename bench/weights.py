@@ -18,7 +18,9 @@ program's values is used); every value is drawn here:
   too, each adding exp(0) to the softmax's sum.
 
 The same seed gives the same bits, so the reference can make them again
-after the program's state is freed.
+after the program's state is freed. Given shardings, each device draws only
+its own shard of each leaf, and the bits are those of the unsharded draw
+(JAX's partitionable threefry).
 """
 from __future__ import annotations
 
@@ -46,14 +48,17 @@ def _leaf(key, path: str, sds, vocab: int):
     return x.astype(dtype)
 
 
-def make(shapes, seed: int, vocab: int):
+def make(shapes, seed: int, vocab: int, shardings=None):
     """A tree of arrays shaped like `shapes` (ShapeDtypeStructs), from
-    `seed`, for a model of `vocab` token ids."""
+    `seed`, for a model of `vocab` token ids; laid out as `shardings` (a
+    tree like `shapes`) where given, else on the default device."""
     flat, tree = jax.tree_util.tree_flatten_with_path(shapes)
     paths = tuple(jax.tree_util.keystr(k) for k, _ in flat)
     sds = tuple(v for _, v in flat)
+    out = {} if shardings is None else {
+        "out_shardings": jax.tree_util.tree_leaves(shardings)}
 
-    @functools.partial(jax.jit, static_argnums=())
+    @functools.partial(jax.jit, **out)
     def build(key):
         keys = jax.random.split(key, len(sds))
         return [_leaf(keys[i], paths[i], sds[i], vocab)
